@@ -98,6 +98,8 @@ def main() -> None:
     ap.add_argument("--artifacts", default=None, metavar="DIR",
                     help="persist BENCH_<module>.json artifacts here")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     only = args.only.split(",") if args.only else None
     art_dir = Path(args.artifacts) if args.artifacts else None
 

@@ -15,6 +15,4 @@ Layers:
   launch/      mesh.py, dryrun.py, train.py, serve.py
 """
 
-from repro import compat as _compat  # noqa: F401  (jax API shims, see module)
-
 __version__ = "0.1.0"

@@ -18,9 +18,9 @@ Pipeline per coordinate i (one inverted list):
 TPU adaptation: assignment inner products are computed either by
 gathers against densified representatives (``cluster_mode="gather"``,
 cheap on CPU) or by scatter-to-dense + one MXU matmul per list
-(``cluster_mode="matmul"``, the TPU-native path). Lists are processed
-in ``lax.map`` chunks so peak memory stays at
-``chunk * beta * dim`` floats.
+(``cluster_mode="matmul"``). ``build_index`` drives the lists from the
+host in fixed-size chunks written in place, and skips coordinates
+without postings.
 """
 from __future__ import annotations
 
@@ -313,30 +313,54 @@ def doc_block_map(index: SeismicIndex) -> DocBlockMap:
                        block_ids[order])
 
 
-@partial(jax.jit, static_argnames=("cfg", "list_chunk"))
-def build_index(docs: PaddedSparse, cfg: SeismicConfig = SeismicConfig(),
-                *, list_chunk: int = 64) -> SeismicIndex:
-    """Algorithm 1 over the whole collection. ``list_chunk`` bounds peak
-    memory of the per-list map (chunk * n_blocks * dim floats)."""
+@jax.jit
+def _postings(docs: PaddedSparse):
+    """Sorted (coord, val, doc) triples plus each coordinate's start
+    offset and posting count in them."""
     d = docs.dim
     sorted_c, sorted_v, sorted_d = _sorted_postings(docs)
     starts = jnp.searchsorted(sorted_c, jnp.arange(d + 1))
     counts = (starts[1:] - starts[:-1]).astype(jnp.int32)
-    starts = starts[:-1].astype(jnp.int32)
+    return sorted_c, sorted_v, sorted_d, starts[:-1].astype(jnp.int32), \
+        counts
+
+
+@partial(jax.jit, static_argnames=("cfg", "lead"))
+def _empty_planes(fwd32: PaddedSparse, cfg: SeismicConfig, lead: tuple):
+    """Every per-list plane, filled with what the per-list build gives
+    a coordinate without postings (the same arrays for every such
+    coordinate: nothing in it depends on the coordinate)."""
+    lam = cfg.lam
+    empty = list_block_arrays(
+        jax.random.PRNGKey(cfg.seed), jnp.full((lam,), fwd32.n, jnp.int32),
+        jnp.zeros((lam,), jnp.float32), jnp.int32(0), fwd32, cfg)
+    return tuple(jnp.broadcast_to(x, lead + (fwd32.dim,) + x.shape)
+                 for x in empty)
+
+
+@partial(jax.jit, static_argnames=("cfg",), donate_argnums=(0,))
+def _fill_lists(planes, ids, postings, fwd32: PaddedSparse,
+                cfg: SeismicConfig):
+    """Build the lists ``ids`` and write them into ``planes`` (donated:
+    updated in place, one row slice per list — a scatter would make XLA
+    copy whole planes). Repeated ids write identical rows."""
     key = jax.random.PRNGKey(cfg.seed)
-    fwd32 = docs.astype(jnp.float32)
+    built = jax.vmap(lambda i: _build_one_list(i, key, *postings, fwd32,
+                                               cfg))(ids)
+    lead = planes[0].ndim - built[0].ndim
+    out = []
+    for p, b in zip(planes, built):
+        for j in range(ids.shape[0]):
+            row = b[j][(None,) * (lead + 1)]
+            start = (0,) * lead + (ids[j],) + (0,) * (row.ndim - lead - 1)
+            p = jax.lax.dynamic_update_slice(p, row.astype(p.dtype), start)
+        out.append(p)
+    return tuple(out)
 
-    def body(i):
-        return _build_one_list(i, key, sorted_c, sorted_v, sorted_d,
-                               starts, counts, fwd32, cfg)
 
-    outs = jax.lax.map(body, jnp.arange(d), batch_size=min(list_chunk, d))
-    (list_docs, list_vals, list_len, blk_off, blk_len,
-     sum_coords, sum_q, sum_scale, sum_zero) = outs[:9]
-    sup_coords = sup_q = sup_scale = sup_zero = None
-    if cfg.superblock_fanout > 0:
-        sup_coords, sup_q, sup_scale, sup_zero = outs[9:]
-
+@partial(jax.jit, static_argnames=("cfg", "lead"))
+def _forward_plane(docs: PaddedSparse, cfg: SeismicConfig, lead: tuple):
+    """The served forward plane (and its u8 dequant constants)."""
     fwd_scale = fwd_zero = None
     if cfg.fwd_quant:
         # compact forward index: u8 values (per-doc affine) + u16 coords
@@ -345,6 +369,70 @@ def build_index(docs: PaddedSparse, cfg: SeismicConfig = SeismicConfig(),
         fwd = PaddedSparse(docs.coords.astype(cdt), q, docs.dim)
     else:
         fwd = docs.astype(jnp.dtype(cfg.fwd_dtype))
+    out = (fwd, fwd_scale, fwd_zero)
+    return jax.tree.map(lambda x: x.reshape(lead + x.shape), out)
+
+
+def index_shape(n_docs: int, dim: int, doc_nnz: int,
+                cfg: SeismicConfig) -> SeismicIndex:
+    """The ``SeismicIndex`` that ``build_index`` returns for an
+    ``[n_docs, doc_nnz]`` f32 collection, as ``ShapeDtypeStruct``
+    leaves — for sizing and compiling without building."""
+    docs = PaddedSparse(jax.ShapeDtypeStruct((n_docs, doc_nnz), jnp.int32),
+                        jax.ShapeDtypeStruct((n_docs, doc_nnz), jnp.float32),
+                        dim)
+
+    def assemble(docs):
+        planes = _empty_planes(docs, cfg, ())
+        fwd, fwd_scale, fwd_zero = _forward_plane(docs, cfg, ())
+        sup = planes[9:] if cfg.superblock_fanout > 0 else (None,) * 4
+        return SeismicIndex(fwd, *planes[:9], fwd_scale=fwd_scale,
+                            fwd_zero=fwd_zero, sup_coords=sup[0],
+                            sup_q=sup[1], sup_scale=sup[2],
+                            sup_zero=sup[3], config=cfg)
+
+    return jax.eval_shape(assemble, docs)
+
+
+def build_index(docs: PaddedSparse, cfg: SeismicConfig = SeismicConfig(),
+                *, list_chunk: int = 64, lead: tuple = (),
+                lists=None) -> SeismicIndex:
+    """Algorithm 1 over the whole collection, on the device that holds
+    ``docs``.
+
+    Lists are built ``list_chunk`` coordinates per jitted launch and
+    written in place into preallocated planes, so peak device memory is
+    the index plus one chunk's temporaries (``list_chunk * n_blocks *
+    dim`` floats and the cluster-assignment intermediates). Coordinates
+    without postings are not built: they keep the arrays the per-list
+    build gives an empty list, so the result is the same as building
+    every coordinate. ``lead`` prepends unit axes to every array leaf
+    (``(1,)`` gives one shard of a stacked, sharded index without a
+    copy).
+
+    ``lists`` (coordinates) restricts the build to those lists; the
+    others stay empty. A query reads only the lists of its top-``cut``
+    coordinates (``retrieval.prep.probed_lists``), so such an index
+    answers the queries whose probed lists it holds exactly as the full
+    index does."""
+    fwd32 = docs.astype(jnp.float32)
+    postings = _postings(docs)
+    live = np.flatnonzero(np.asarray(postings[4]) > 0)
+    if lists is not None:
+        live = np.intersect1d(live, np.asarray(lists))
+    planes = _empty_planes(fwd32, cfg, lead)
+    for lo in range(0, live.size, list_chunk):
+        ids = live[lo:lo + list_chunk]
+        ids = np.pad(ids, (0, list_chunk - ids.size), mode="edge")
+        planes = _fill_lists(planes, jnp.asarray(ids, jnp.int32),
+                             postings, fwd32, cfg)
+    del postings
+    (list_docs, list_vals, list_len, blk_off, blk_len,
+     sum_coords, sum_q, sum_scale, sum_zero) = planes[:9]
+    sup_coords = sup_q = sup_scale = sup_zero = None
+    if cfg.superblock_fanout > 0:
+        sup_coords, sup_q, sup_scale, sup_zero = planes[9:]
+    fwd, fwd_scale, fwd_zero = _forward_plane(docs, cfg, lead)
     return SeismicIndex(
         fwd=fwd, list_docs=list_docs, list_vals=list_vals,
         list_len=list_len, block_off=blk_off, block_len=blk_len,

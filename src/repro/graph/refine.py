@@ -94,7 +94,7 @@ def scored_init(ids: jax.Array, n_docs: int) -> jax.Array:
 
 def refine_one_round(index: SeismicIndex, q_dense: jax.Array,
                      scores: jax.Array, ids: jax.Array, ev: jax.Array,
-                     scored: jax.Array, p: SearchParams
+                     scored: jax.Array, p: SearchParams, q=None
                      ) -> tuple[jax.Array, jax.Array, jax.Array,
                                 jax.Array]:
     """ONE expand + rescore + re-merge round.
@@ -125,7 +125,7 @@ def refine_one_round(index: SeismicIndex, q_dense: jax.Array,
         if p.fuse_level >= 1:
             cand = compact_candidates(cand)
         new_s = score_candidates(index, q_dense, cand, p.use_kernel,
-                                 fuse_level=p.fuse_level)
+                                 fuse_level=p.fuse_level, q=q)
     if index.tombstone is not None:
         # stale graph edges may still point at deleted docs between
         # compactions (and, post-compaction, reverse edges toward a
@@ -145,7 +145,7 @@ def refine_one_round(index: SeismicIndex, q_dense: jax.Array,
 
 def refine_batch(index: SeismicIndex, q_dense: jax.Array,
                  scores: jax.Array, ids: jax.Array, ev: jax.Array,
-                 p: SearchParams
+                 p: SearchParams, q=None
                  ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Neighbor-expand + rescore + re-merge the merged top-k.
 
@@ -165,5 +165,5 @@ def refine_batch(index: SeismicIndex, q_dense: jax.Array,
     scored = scored_init(ids, index.n_docs)
     for _ in range(p.refine_rounds):
         scores, ids, ev, scored = refine_one_round(
-            index, q_dense, scores, ids, ev, scored, p)
+            index, q_dense, scores, ids, ev, scored, p, q)
     return scores, ids, ev

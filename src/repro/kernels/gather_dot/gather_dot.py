@@ -17,17 +17,18 @@ fuses into the multiply — candidate values cross HBM as one byte each
 and are never materialized as floats.
 
 Tiling (ops.py pads Q to tile_q and N to tile_n — the row width nnz
-and vocab d pass through as-is, so non-interpret Mosaic lowering
-expects lane-aligned nnz/d; off-TPU coverage is interpret-mode only):
+passes through as the full last dim):
   grid = (Q / tile_q, N / tile_n)   — queries x candidate tiles
-  q block       [tile_q, d]         VMEM-resident dense query tile
+  q pairs       [tile_q, nq]        query (coord, value) pairs in SMEM
   coords/vals   [tile_q, tile_n, nnz]
   scale/zero    [tile_q, tile_n]    (quantized variant only)
   out           [tile_q, tile_n]
 
-The per-row dynamic gather lowers through the TPU gather/scatter unit
-on current Mosaic; interpret mode (auto-selected off-TPU by ops.py)
-runs the same program on CPU for the ref.py parity tests.
+Each query row of the tile is scored on its own [tile_n, nnz] slab,
+with the query weight of every candidate coordinate matched from the
+row's pairs (:mod:`repro.kernels.sparse_query`) — the form Mosaic
+lowers for v5e. Interpret mode (auto-selected off-TPU by ops.py) runs
+the same program on CPU for the ref.py parity tests.
 """
 from __future__ import annotations
 
@@ -37,63 +38,58 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-
-def _gather(q, coords):
-    tq, tn, nnz = coords.shape
-    return jnp.take_along_axis(
-        q, coords.reshape(tq, tn * nnz), axis=1).reshape(tq, tn, nnz)
+from repro.kernels.sparse_query import match_gather, pair_spec
 
 
-def _gather_dot_kernel(q_ref, coords_ref, vals_ref, out_ref):
-    q = q_ref[...]                              # [tq, d]
-    coords = coords_ref[...]                    # [tq, tn, nnz]
-    vals = vals_ref[...].astype(q.dtype)
-    out_ref[...] = (_gather(q, coords) * vals).sum(axis=-1)
+def _gather_dot_kernel(qc_ref, qv_ref, coords_ref, vals_ref, out_ref):
+    for i in range(coords_ref.shape[0]):        # query rows of the tile
+        g = match_gather(qc_ref, qv_ref, i, coords_ref[i])  # [tn, nnz]
+        out_ref[i] = (g * vals_ref[i].astype(jnp.float32)).sum(axis=-1)
 
 
-def _gather_dot_quant_kernel(q_ref, coords_ref, vals_ref, scale_ref,
-                             zero_ref, out_ref):
-    q = q_ref[...]                              # [tq, d]
-    coords = coords_ref[...]                    # [tq, tn, nnz]
-    u8 = vals_ref[...].astype(q.dtype)          # [tq, tn, nnz]
-    scale = scale_ref[...].astype(q.dtype)      # [tq, tn]
-    zero = zero_ref[...].astype(q.dtype)
-    deq = (u8 - 1.0) * scale[..., None] + zero[..., None]
-    deq = jnp.where(u8 > 0, deq, 0.0)           # level 0 == padding
-    out_ref[...] = (_gather(q, coords) * deq).sum(axis=-1)
+def _gather_dot_quant_kernel(qc_ref, qv_ref, coords_ref, vals_ref,
+                             scale_ref, zero_ref, out_ref):
+    for i in range(coords_ref.shape[0]):        # query rows of the tile
+        g = match_gather(qc_ref, qv_ref, i, coords_ref[i])  # [tn, nnz]
+        u8 = vals_ref[i].astype(jnp.int32).astype(jnp.float32)
+        deq = (u8 - 1.0) * scale_ref[i][:, None] + zero_ref[i][:, None]
+        deq = jnp.where(u8 > 0, deq, 0.0)       # level 0 == padding
+        out_ref[i] = (g * deq).sum(axis=-1)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("tile_q", "tile_n", "interpret"))
-def gather_dot_batch_pallas(q_dense: jax.Array, coords: jax.Array,
-                            vals: jax.Array, scale: jax.Array | None = None,
+def gather_dot_batch_pallas(q_coords: jax.Array, q_vals: jax.Array,
+                            coords: jax.Array, vals: jax.Array,
+                            scale: jax.Array | None = None,
                             zero: jax.Array | None = None, *,
                             tile_q: int = 8, tile_n: int = 128,
                             interpret: bool = True) -> jax.Array:
-    """scores [Q, N] = sum_j q_dense[q, coords[q, :, j]] * vals[q, :, j].
+    """scores [Q, N] f32 = sum_j q[q, coords[q, :, j]] * vals[q, :, j],
+    with the query given as (i32 coords, f32 vals) pairs [Q, nq].
 
     Q must be a multiple of tile_q and N of tile_n (ops.py pads). With
     (scale, zero) given, vals is u8 and dequant fuses into the dot.
     """
     qn, n, nnz = coords.shape
-    d = q_dense.shape[1]
-    assert q_dense.shape[0] == qn and qn % tile_q == 0 and n % tile_n == 0, (
-        q_dense.shape, coords.shape, tile_q, tile_n)
+    nq = q_coords.shape[1]
+    assert q_coords.shape[0] == qn and qn % tile_q == 0 and n % tile_n == 0, (
+        q_coords.shape, coords.shape, tile_q, tile_n)
     grid = (qn // tile_q, n // tile_n)
-    q_spec = pl.BlockSpec((tile_q, d), lambda i, j: (i, 0))
+    q_spec = pair_spec(tile_q, nq)
     row_spec = pl.BlockSpec((tile_q, tile_n, nnz), lambda i, j: (i, j, 0))
     sz_spec = pl.BlockSpec((tile_q, tile_n), lambda i, j: (i, j))
     quant = scale is not None
     kernel = _gather_dot_quant_kernel if quant else _gather_dot_kernel
-    in_specs = [q_spec, row_spec, row_spec] + ([sz_spec, sz_spec] if quant
-                                               else [])
-    args = (q_dense, coords, vals) + ((scale, zero) if quant else ())
+    in_specs = [q_spec, q_spec, row_spec, row_spec] \
+        + ([sz_spec, sz_spec] if quant else [])
+    args = (q_coords, q_vals, coords, vals) + ((scale, zero) if quant else ())
     return pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=in_specs,
         out_specs=sz_spec,
-        out_shape=jax.ShapeDtypeStruct((qn, n), q_dense.dtype),
+        out_shape=jax.ShapeDtypeStruct((qn, n), jnp.float32),
         interpret=interpret,
     )(*args)
 

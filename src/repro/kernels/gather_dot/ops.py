@@ -28,6 +28,7 @@ from repro.kernels.gather_dot.gather_dot import (gather_dot_batch_pallas,
                                                  gather_dot_pallas)
 from repro.kernels.gather_dot.ref import gather_dot_batch_ref, gather_dot_ref
 from repro.kernels.runtime import default_interpret
+from repro.kernels.sparse_query import pad_pairs, query_pairs
 from repro.kernels.tiling import TileChoice, choose_tiles, gather_row_bytes
 
 _TILE_Q = 8     # minimum aligned tile (f32 sublane) — chooser floor
@@ -46,43 +47,45 @@ def cand_tile_choice(qn: int, c: int, nnz: int, *, quant: bool,
                         q_row_bytes=4 * dim)
 
 
-def _pad_batch_call(q_dense, coords, vals, scale, zero, *,
+def _pad_batch_call(q, coords, vals, scale, zero, *,
                     tile_q=None, tile_n=None, interpret=None):
     """Choose tiles, pad Q/N up to them, launch, slice back."""
     interpret = default_interpret(interpret)
+    qc, qv, out_dtype = query_pairs(q)
     qn, n, nnz = coords.shape
     if tile_q is None or tile_n is None:
         ch = choose_tiles(qn, n,
                           row_bytes=gather_row_bytes(
                               nnz, quant=scale is not None),
-                          q_row_bytes=4 * q_dense.shape[1])
+                          q_row_bytes=0)      # query pairs live in SMEM
         tile_q = tile_q if tile_q is not None else ch.tile_q
         tile_n = tile_n if tile_n is not None else ch.tile_n
     pq = (-qn) % tile_q
     pn = (-n) % tile_n
+    qc, qv = pad_pairs(qc, qv, pq)
     if pq or pn:
-        q_dense = jnp.pad(q_dense, ((0, pq), (0, 0)))
         coords = jnp.pad(coords, ((0, pq), (0, pn), (0, 0)))
         vals = jnp.pad(vals, ((0, pq), (0, pn), (0, 0)))
         if scale is not None:
             scale = jnp.pad(scale, ((0, pq), (0, pn)))
             zero = jnp.pad(zero, ((0, pq), (0, pn)))
-    out = gather_dot_batch_pallas(q_dense, coords, vals, scale, zero,
+    out = gather_dot_batch_pallas(qc, qv, coords, vals, scale, zero,
                                   tile_q=tile_q, tile_n=tile_n,
                                   interpret=interpret)
-    return out[:qn, :n]
+    return out[:qn, :n].astype(out_dtype)
 
 
-def gather_dot_batch(q_dense: jax.Array, coords: jax.Array,
+def gather_dot_batch(q, coords: jax.Array,
                      vals: jax.Array, scale: jax.Array | None = None,
                      zero: jax.Array | None = None, *,
                      tile_q: int | None = None, tile_n: int | None = None,
                      interpret: bool | None = None) -> jax.Array:
-    """Batched sparse·dense scoring [Q, N, nnz] -> [Q, N].
+    """Batched sparse·dense scoring [Q, N, nnz] -> [Q, N]; ``q`` is a
+    ``PaddedSparse`` query batch or a dense ``[Q, d]`` one.
 
     With (scale, zero) given, ``vals`` is uint8 and the per-doc affine
     dequantization fuses into the kernel (compact forward index)."""
-    return _pad_batch_call(q_dense, coords, vals, scale, zero,
+    return _pad_batch_call(q, coords, vals, scale, zero,
                            tile_q=tile_q, tile_n=tile_n, interpret=interpret)
 
 

@@ -3,10 +3,13 @@ over a synthetic collection and serves batched queries; reports
 throughput, recall, and docs-evaluated telemetry.
 
   PYTHONPATH=src python -m repro.launch.serve --n-docs 8192 --queries 256
-  PYTHONPATH=src python -m repro.launch.serve --devices 8 --doc-shards 4
+  PYTHONPATH=src python -m repro.launch.serve --doc-shards 4
+
+``--doc-shards`` shards the corpus over the visible devices (one shard
+per device when there are enough), one data-parallel row per group of
+``doc_shards`` devices.
 """
 import argparse
-import os
 import time
 
 
@@ -18,13 +21,11 @@ def main():
     ap.add_argument("--k", type=int, default=10)
     ap.add_argument("--budget", type=int, default=16)
     ap.add_argument("--cut", type=int, default=10)
-    ap.add_argument("--devices", type=int, default=0)
     ap.add_argument("--doc-shards", type=int, default=1)
     args = ap.parse_args()
 
-    if args.devices:
-        os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
-                                   + f" --xla_force_host_platform_device_count={args.devices}")
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     import numpy as np
     import jax
     import jax.numpy as jnp
@@ -51,12 +52,14 @@ def main():
 
     if args.doc_shards > 1:
         from repro.core.distributed import (build_sharded_index,
-                                            make_distributed_search)
+                                            make_distributed_search,
+                                            place_on_mesh)
         n_dev = len(jax.devices())
         mesh = jax.make_mesh((n_dev // args.doc_shards, args.doc_shards),
                              ("data", "model"),
                              axis_types=(jax.sharding.AxisType.Auto,) * 2)
-        stacked = build_sharded_index(docs, icfg, args.doc_shards)
+        stacked = place_on_mesh(
+            build_sharded_index(docs_np, icfg, args.doc_shards), mesh)
         search = make_distributed_search(mesh, p)
         with jax.set_mesh(mesh):
             t0 = time.time()

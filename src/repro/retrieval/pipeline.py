@@ -20,6 +20,7 @@ from functools import partial
 from typing import TYPE_CHECKING, Callable
 
 import jax
+import jax.numpy as jnp
 
 from repro.graph.refine import refine_batch
 from repro.retrieval.merge import merge_topk
@@ -44,12 +45,20 @@ def run_pipeline(index: SeismicIndex, q_coords: jax.Array,
     """
     select = get_selector(p.policy)                 # static under jit
     q_dense, lists, _ = prep_queries(q_coords, q_vals, index.dim, p.cut)
-    batch = route_batch(index, q_dense, lists, p)
+    q = _sparse_query(index, q_coords, q_vals)
+    batch = route_batch(index, q_dense, lists, p, q)
     sel = select(index, batch, p)
     cand, scores = score_selection(index, batch, sel, p.use_kernel,
                                    fuse_level=p.fuse_level)
     top_s, top_ids, ev = merge_topk(cand, scores, p.k, index.n_docs)
-    return refine_batch(index, q_dense, top_s, top_ids, ev, p)
+    return refine_batch(index, q_dense, top_s, top_ids, ev, p, q)
+
+
+def _sparse_query(index: SeismicIndex, q_coords, q_vals) -> PaddedSparse:
+    """The query batch as the kernels score it (f32 values, like the
+    dense rows ``prep_queries`` builds)."""
+    return PaddedSparse(q_coords.astype(jnp.int32),
+                        q_vals.astype(jnp.float32), index.dim)
 
 
 @partial(jax.jit, static_argnames=("p",))
@@ -84,17 +93,18 @@ def stage_fns(index: SeismicIndex, p: SearchParams
         "prep": jax.jit(
             lambda c, v: prep_queries(c, v, index.dim, p.cut)),
         "router": jax.jit(
-            lambda qd, ls: route_batch(index, qd, ls, p)),
+            lambda qd, ls, q=None: route_batch(index, qd, ls, p, q)),
         "selector": jax.jit(lambda b: select(index, b, p)),
         "scorer": jax.jit(
             lambda b, s: score_selection(index, b, s, p.use_kernel,
                                          fuse_level=p.fuse_level)),
         "merge": jax.jit(lambda c, s: merge_topk(c, s, p.k, index.n_docs)),
         "refine": jax.jit(
-            lambda qd, s, i, e: refine_batch(index, qd, s, i, e, p)),
+            lambda qd, s, i, e, q=None: refine_batch(index, qd, s, i, e,
+                                                     p, q)),
         "refine_round": jax.jit(
-            lambda qd, s, i, e, sc: refine_one_round(index, qd, s, i, e,
-                                                     sc, p)),
+            lambda qd, s, i, e, sc, q=None: refine_one_round(
+                index, qd, s, i, e, sc, p, q)),
     }
 
 
@@ -141,7 +151,8 @@ def run_pipeline_staged(index: SeismicIndex, q_coords: jax.Array,
         return out
 
     q_dense, lists, _ = timed("prep", fns["prep"], q_coords, q_vals)
-    batch = timed("router", fns["router"], q_dense, lists)
+    q = _sparse_query(index, q_coords, q_vals)
+    batch = timed("router", fns["router"], q_dense, lists, q)
     sel = timed("selector", fns["selector"], batch)
     cand, scores = timed("scorer", fns["scorer"], batch, sel)
     if probe is not None:
@@ -153,7 +164,8 @@ def run_pipeline_staged(index: SeismicIndex, q_coords: jax.Array,
     if audit and probe is not None:
         probe("merge_ids", top_ids)
     if not (split_refine and p.refine_rounds > 0 and p.graph_degree > 0):
-        return timed("refine", fns["refine"], q_dense, top_s, top_ids, ev)
+        return timed("refine", fns["refine"], q_dense, top_s, top_ids, ev,
+                     q)
     # round-by-round refine: same ops as refine_batch, one jit boundary
     # per round so each round's wall time is attributable
     from repro.graph.refine import scored_init, validate_refine_params
@@ -163,7 +175,7 @@ def run_pipeline_staged(index: SeismicIndex, q_coords: jax.Array,
     s, i, e = top_s, top_ids, ev
     for j in range(p.refine_rounds):
         s, i, e, scored = timed(f"refine_round_{j}", fns["refine_round"],
-                                q_dense, s, i, e, scored)
+                                q_dense, s, i, e, scored, q)
     t1 = time.monotonic()
     if record is not None:
         record("refine", t1 - t0)

@@ -7,6 +7,7 @@ batch with one top_k.
 """
 from __future__ import annotations
 
+import numpy as np
 import jax
 import jax.numpy as jnp
 
@@ -28,3 +29,12 @@ def prep_queries(q_coords: jax.Array, q_vals: jax.Array, dim: int,
     cc = jnp.where(cv > 0, cc, 0)
     cv = jnp.where(cv > 0, cv, 0.0)
     return q_dense, cc.astype(jnp.int32), cv
+
+
+def probed_lists(q_coords, q_vals, dim: int, cut: int):
+    """The distinct coordinates whose inverted lists a query batch
+    reads (host numpy array) — exactly the lists ``prep_queries``
+    probes."""
+    _, lists, _ = prep_queries(jnp.asarray(q_coords), jnp.asarray(q_vals),
+                               dim, cut)
+    return np.unique(np.asarray(lists))

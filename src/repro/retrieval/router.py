@@ -42,6 +42,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.retrieval.params import SearchParams
+from repro.sparse.ops import PaddedSparse
 from repro.sparse.quant import dequantize_u8
 
 if TYPE_CHECKING:  # annotation-only: keeps repro.retrieval import-cycle-free
@@ -58,13 +59,17 @@ class RoutedBatch:
     q_dense: jax.Array   # f32 [Q, d]
     lists: jax.Array     # i32 [Q, cut]     probed coordinate per slot
     r: jax.Array         # f32 [Q, cut*nb]  block summary scores (-inf dead)
+    q: PaddedSparse | None = None   # the padded-sparse query the kernels
+    #                                 score from (None: they fall back
+    #                                 to the dense rows, exact but slow)
 
 
-def _summary_scores(q_dense, sc, sq, scale, zero, use_kernel):
+def _summary_scores(q_dense, sc, sq, scale, zero, use_kernel, q=None):
     """<q, dequant(summary)> over a flat [Q, L, S] summary axis."""
     if use_kernel:
         from repro.kernels.summary_dot.ops import summary_dot_batch
-        return summary_dot_batch(q_dense, sc, sq, scale, zero)
+        return summary_dot_batch(q_dense if q is None else q, sc, sq,
+                                 scale, zero)
     qn = sc.shape[0]
     sv = dequantize_u8(sq, scale, zero)
     gathered = jnp.take_along_axis(
@@ -73,14 +78,14 @@ def _summary_scores(q_dense, sc, sq, scale, zero, use_kernel):
 
 
 def _route_flat(index: SeismicIndex, q_dense: jax.Array, lists: jax.Array,
-                p: SearchParams) -> RoutedBatch:
+                p: SearchParams, q: PaddedSparse | None) -> RoutedBatch:
     """Summary inner products for all blocks of the probed lists."""
     if p.fuse_level >= 2:
         from repro.kernels.router_fused import router_flat_batch
         r = router_flat_batch(lists, q_dense, index.sum_coords,
                               index.sum_q, index.sum_scale,
                               index.sum_zero, index.block_len)
-        return RoutedBatch(q_dense=q_dense, lists=lists, r=r)
+        return RoutedBatch(q_dense=q_dense, lists=lists, r=r, q=q)
     qn, cut = lists.shape
     nb = index.config.n_blocks
     s = index.sum_coords.shape[-1]
@@ -88,14 +93,15 @@ def _route_flat(index: SeismicIndex, q_dense: jax.Array, lists: jax.Array,
     sq = index.sum_q[lists].reshape(qn, cut * nb, s)
     scale = index.sum_scale[lists].reshape(qn, cut * nb)
     zero = index.sum_zero[lists].reshape(qn, cut * nb)
-    r = _summary_scores(q_dense, sc, sq, scale, zero, p.use_kernel)
+    r = _summary_scores(q_dense, sc, sq, scale, zero, p.use_kernel, q)
     alive = (index.block_len[lists] > 0).reshape(qn, cut * nb)
     r = jnp.where(alive, r, NEG)
-    return RoutedBatch(q_dense=q_dense, lists=lists, r=r)
+    return RoutedBatch(q_dense=q_dense, lists=lists, r=r, q=q)
 
 
 def _route_hierarchical(index: SeismicIndex, q_dense: jax.Array,
-                        lists: jax.Array, p: SearchParams) -> RoutedBatch:
+                        lists: jax.Array, p: SearchParams,
+                        q: PaddedSparse | None) -> RoutedBatch:
     """Superblock tier -> survivors -> child block summaries.
 
     Pruning is justified by upper bounds: a block is pruned only when
@@ -118,14 +124,14 @@ def _route_hierarchical(index: SeismicIndex, q_dense: jax.Array,
             index.block_len, m=m, fanout=f)
         r = jnp.full((qn, cut * nb), NEG, q_dense.dtype)
         r = r.at[jnp.arange(qn)[:, None], flat].max(rb)
-        return RoutedBatch(q_dense=q_dense, lists=lists, r=r)
+        return RoutedBatch(q_dense=q_dense, lists=lists, r=r, q=q)
     s2 = index.sup_coords.shape[-1]
     # ---- stage A: coarse tier, one batched summary_dot over cut * ns
     sc = index.sup_coords[lists].reshape(qn, cut * ns, s2)
     sq = index.sup_q[lists].reshape(qn, cut * ns, s2)
     scale = index.sup_scale[lists].reshape(qn, cut * ns)
     zero = index.sup_zero[lists].reshape(qn, cut * ns)
-    u = _summary_scores(q_dense, sc, sq, scale, zero, p.use_kernel)
+    u = _summary_scores(q_dense, sc, sq, scale, zero, p.use_kernel, q)
     # a superblock is alive iff any child block is (all-padding -> -inf)
     blk_alive = jnp.pad(index.block_len > 0, ((0, 0), (0, (-nb) % f)))
     sup_alive = blk_alive.reshape(-1, ns, f).any(-1)        # [L, ns]
@@ -147,7 +153,7 @@ def _route_hierarchical(index: SeismicIndex, q_dense: jax.Array,
     rb = _summary_scores(q_dense, bsc.reshape(qn, m * f, s),
                          bsq.reshape(qn, m * f, s),
                          bscale.reshape(qn, m * f),
-                         bzero.reshape(qn, m * f), p.use_kernel)
+                         bzero.reshape(qn, m * f), p.use_kernel, q)
     alive = (in_range
              & (index.block_len[coord[..., None], child] > 0)
              & jnp.isfinite(us)[..., None])                 # [Q, M, f]
@@ -156,16 +162,18 @@ def _route_hierarchical(index: SeismicIndex, q_dense: jax.Array,
     flat = (li[..., None] * nb + child).reshape(qn, m * f)
     r = jnp.full((qn, cut * nb), NEG, q_dense.dtype)
     r = r.at[jnp.arange(qn)[:, None], flat].max(rb)
-    return RoutedBatch(q_dense=q_dense, lists=lists, r=r)
+    return RoutedBatch(q_dense=q_dense, lists=lists, r=r, q=q)
 
 
 def route_batch(index: SeismicIndex, q_dense: jax.Array, lists: jax.Array,
-                p: SearchParams) -> RoutedBatch:
+                p: SearchParams, q: PaddedSparse | None = None
+                ) -> RoutedBatch:
     """Phase R for the whole batch; flat or hierarchical per
     ``p.superblock_fanout`` (0 = flat, bit-exact with the single-tier
-    router)."""
+    router). ``q`` is the padded-sparse form of ``q_dense``, which the
+    kernel path scores from; it rides on the batch to the scorer."""
     if p.superblock_fanout <= 0:
-        return _route_flat(index, q_dense, lists, p)
+        return _route_flat(index, q_dense, lists, p, q)
     if index.sup_coords is None:
         raise ValueError(
             "hierarchical routing requested (superblock_fanout="
@@ -176,7 +184,7 @@ def route_batch(index: SeismicIndex, q_dense: jax.Array, lists: jax.Array,
             f"superblock_fanout mismatch: SearchParams has "
             f"{p.superblock_fanout}, index was built with "
             f"{index.config.superblock_fanout}")
-    return _route_hierarchical(index, q_dense, lists, p)
+    return _route_hierarchical(index, q_dense, lists, p, q)
 
 
 def router_work(cfg, p: SearchParams) -> int:

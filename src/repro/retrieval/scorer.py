@@ -29,6 +29,7 @@ import jax.numpy as jnp
 
 from repro.retrieval.router import NEG, RoutedBatch
 from repro.retrieval.selector import Selection
+from repro.sparse.ops import PaddedSparse
 from repro.sparse.quant import dequantize_u8
 
 if TYPE_CHECKING:  # annotation-only: keeps repro.retrieval import-cycle-free
@@ -127,14 +128,16 @@ def compact_candidates(cand: jax.Array) -> jax.Array:
 
 def score_candidates(index: SeismicIndex, q_dense: jax.Array,
                      cand: jax.Array, use_kernel: bool, *,
-                     fuse_level: int = 0) -> jax.Array:
+                     fuse_level: int = 0,
+                     q: PaddedSparse | None = None) -> jax.Array:
     """Exact <q, doc> for candidate ids [Q, C] (sentinel -> -inf).
 
     With a compact (fwd_quant) index the per-doc u8 dequant fuses into
     the gather-dot; scores stay 'exact' up to ~0.4% value quantization.
     At ``fuse_level >= 1`` the candidate-driven kernel gathers forward
     rows in-kernel and skips all-sentinel tiles (see module docstring);
-    ``use_kernel`` governs only the unfused path.
+    ``use_kernel`` governs only the unfused path, which scores from
+    the padded-sparse query ``q`` when given (the dense rows otherwise).
     """
     if fuse_level >= 1:
         from repro.kernels.gather_dot.ops import gather_dot_cand_batch
@@ -151,7 +154,8 @@ def score_candidates(index: SeismicIndex, q_dense: jax.Array,
         zero = jnp.take(index.fwd_zero, cand, mode="clip")
     if use_kernel:
         from repro.kernels.gather_dot.ops import gather_dot_batch
-        scores = gather_dot_batch(q_dense, c, v, scale, zero)
+        scores = gather_dot_batch(q_dense if q is None else q, c, v,
+                                  scale, zero)
     else:
         if quant:
             v = dequantize_u8(v, scale, zero)
@@ -188,7 +192,7 @@ def score_selection(index: SeismicIndex, batch: RoutedBatch,
     if fuse_level >= 1:
         cand = compact_candidates(cand)
     scores = score_candidates(index, batch.q_dense, cand, use_kernel,
-                              fuse_level=fuse_level)
+                              fuse_level=fuse_level, q=batch.q)
     if index.tail_ids is not None:
         tail_cand, tail_scores = score_tail(index, batch.q_dense)
         cand = jnp.concatenate([cand, tail_cand], axis=1)

@@ -111,7 +111,7 @@ def select_adaptive(index: SeismicIndex, batch: RoutedBatch,
     if p.fuse_level >= 1:
         cand1 = compact_candidates(cand1)
     s1 = score_candidates(index, batch.q_dense, cand1, p.use_kernel,
-                          fuse_level=p.fuse_level)
+                          fuse_level=p.fuse_level, q=batch.q)
     theta = jax.lax.top_k(s1, p.k)[0][:, -1]                # [Q]
     theta = jnp.where(jnp.isfinite(theta), theta, NEG)
     # ---- stage 2: Alg. 2 line 6 -> keep blocks w/ r >= theta/heap_factor

@@ -25,11 +25,14 @@ Two topologies:
                launch-width ladder.
 
   ``shard``    replica r owns doc shard r of a ``build_sharded_index``
-               stacked pytree. Every batch fans out to ALL replicas;
-               each scores its shard locally, globalizes + masks pad
-               hits via ``core.distributed.mask_shard_topk`` (the same
-               invariant the ``shard_map`` path applies before its
-               all-gather), and the last-finishing replica merges the
+               stacked pytree, on the device that holds it (shard s
+               on device s when there are as many devices as shards).
+               Every batch fans out to ALL replicas; each scores its
+               shard locally through ``core.distributed.search_shard``,
+               which globalizes + masks pad hits via
+               ``mask_shard_topk`` (the same invariant the
+               ``shard_map`` path applies before its all-gather), and
+               the last-finishing replica merges the
                per-shard top-k with the existing ``merge_topk`` and
                fulfils the batch. ``docs_evaluated`` is the sum over
                shards. This is the thread-parallel twin of
@@ -61,12 +64,13 @@ from __future__ import annotations
 import queue as _queue
 import threading
 import time
+from concurrent.futures import ThreadPoolExecutor
 
 import numpy as np
 import jax
 import jax.numpy as jnp
 
-from repro.core.distributed import mask_shard_topk
+from repro.core.distributed import search_shard, shard_views
 from repro.retrieval import SearchParams
 from repro.retrieval.merge import merge_topk
 from repro.serve.balancer import StageTimingBalancer
@@ -180,9 +184,8 @@ class ReplicaSeismicServer(AsyncSeismicServer):
             if kw.get("stage_timing"):
                 raise ValueError("stage_timing is mirror-mode only; "
                                  "shard launches run fused per shard")
-            shards = [jax.tree.map(lambda x, s=s: x[s], index)
-                      for s in range(n_shards)]
-            representative = shards[0]
+            shards = shard_views(index)
+            representative = _unstacked_shape(shards[0])
         self.mode = mode
         self.n_replicas = n_replicas
         self.mailbox_depth = mailbox_depth
@@ -195,7 +198,7 @@ class ReplicaSeismicServer(AsyncSeismicServer):
             self.per_shard = representative.fwd.coords.shape[0]
             self.n_docs = n_docs if n_docs is not None \
                 else n_replicas * self.per_shard
-            self._replicas = [(s, None) for s in shards]
+            self._replicas = [(view, None) for view in shards]
             k, nd = self.params.k, self.n_docs
             self._merge = jax.jit(
                 lambda cand, scores: merge_topk(cand, scores, k, nd))
@@ -281,9 +284,25 @@ class ReplicaSeismicServer(AsyncSeismicServer):
         return super().start(warmup=warmup)
 
     def warmup(self) -> None:
-        super().warmup()
-        if self.mode == "shard":
-            self._warmup_merge(self._merge, self.params.k)
+        if self.mode == "mirror":
+            super().warmup()
+            return
+        self._warmup_shards(self._replicas, self.params, self.n_docs)
+        self._warmup_merge(self._merge, self.params.k)
+
+    def _warmup_shards(self, replicas, params, n_docs) -> None:
+        """Compile every ladder width on every shard's device (a
+        program is compiled per device it runs on; one thread per
+        shard so the compiles overlap)."""
+        def warm(view):
+            for width in self.launch_widths:
+                coords = jnp.zeros((width, self.query_nnz), jnp.int32)
+                vals = jnp.zeros((width, self.query_nnz), jnp.float32)
+                jax.block_until_ready(
+                    search_shard(view, coords, vals, 0, params, n_docs))
+
+        with ThreadPoolExecutor(len(replicas)) as pool:
+            list(pool.map(warm, [view for view, _ in replicas]))
 
     def _warmup_merge(self, merge, k: int) -> None:
         for width in self.launch_widths:
@@ -319,9 +338,8 @@ class ReplicaSeismicServer(AsyncSeismicServer):
             raise ValueError(
                 f"stacked index has {n_shards} shards; server has "
                 f"{self.n_replicas} replicas (shard swap cannot resize)")
-        shards = [jax.tree.map(lambda x, s=s: x[s], index)
-                  for s in range(n_shards)]
-        rep = shards[0]
+        shards = shard_views(index)
+        rep = _unstacked_shape(shards[0])
         from repro.graph.refine import validate_refine_params
         from repro.tune.policy import validate_tuned_index
         validate_refine_params(rep, params)
@@ -331,15 +349,16 @@ class ReplicaSeismicServer(AsyncSeismicServer):
         k = params.k
         merge = jax.jit(
             lambda cand, scores: merge_topk(cand, scores, k, nd))
+        replicas = [(view, None) for view in shards]
         if warmup:
-            self._warmup_for(rep, params, None)
+            self._warmup_shards(replicas, params, nd)
             self._warmup_merge(merge, k)
         with self._swap_lock:
             self._publish_swap(rep, params, None, None)
             self.per_shard = per_shard
             self.n_docs = nd
             self._merge = merge
-            self._replicas = [(s, None) for s in shards]
+            self._replicas = replicas
             epoch = self.epoch
         self._register_gauges()
         self.telemetry.inc("swaps")
@@ -426,16 +445,18 @@ class ReplicaSeismicServer(AsyncSeismicServer):
         state comes from the job's dispatch-time view, never ``self``
         (see ``_ShardJob.view``)."""
         replicas, per_shard, n_docs, _ = job.view
-        index, _ = replicas[rid]
-        ids, scores, ev, t0, t1, _, _ = self._execute(
-            index, None, job.coords, job.vals, False, self._delay[rid])
+        view, _ = replicas[rid]
+        t0 = time.monotonic()
+        if self._delay[rid] > 0.0:
+            time.sleep(self._delay[rid])
+        # runs on the device holding shard rid; pad hits are masked to
+        # (-inf, -1) inside, before anything crosses the shard boundary
+        m_scores, m_gids, ev = jax.block_until_ready(search_shard(
+            view, jnp.asarray(job.coords), jnp.asarray(job.vals),
+            rid * per_shard, self.params, n_docs))
+        t1 = time.monotonic()
         self._on_timing(rid, t1 - t0, {})
-        # same invariant as the shard_map path: mask pad hits to
-        # (-inf, -1) BEFORE anything crosses the shard boundary
-        m_scores, m_gids = mask_shard_topk(
-            jnp.asarray(scores), jnp.asarray(ids), index.fwd,
-            rid * per_shard, n_docs=n_docs)
-        part = (np.asarray(m_gids), np.asarray(m_scores), ev)
+        part = (np.asarray(m_gids), np.asarray(m_scores), np.asarray(ev))
         if job.add(rid, part, t0, t1):
             self._finish_shard_job(job)
 
@@ -474,3 +495,9 @@ class ReplicaSeismicServer(AsyncSeismicServer):
                      span_attrs={"replica": "shard-merge",
                                  "n_shards": self.n_replicas},
                      audit_span=audit_span)
+
+
+def _unstacked_shape(view):
+    """Shape-only stand-in for one shard's unstacked index: what the
+    base server validates and sizes from, without copying the shard."""
+    return jax.eval_shape(lambda v: jax.tree.map(lambda x: x[0], v), view)

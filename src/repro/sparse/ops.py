@@ -88,16 +88,21 @@ def alpha_mass_subvector(coords: jax.Array, vals: jax.Array, alpha: float,
     """Definition 3.1: keep the largest-|value| entries while their
     cumulative L1 mass stays within ``alpha * ||x||_1``; at least one
     entry is always kept. Output is padded to ``out_nnz`` entries.
+
+    Only the ``out_nnz`` largest entries can be kept, so a top-k (ties
+    to the lower index, as a stable sort) replaces sorting the whole
+    vector — on a TPU a d=30522 summary row sorts ~20x slower than its
+    top-96 selects.
     """
-    order = jnp.argsort(-jnp.abs(vals))
+    mag, order = jax.lax.top_k(jnp.abs(vals), min(out_nnz, vals.shape[0]))
     sv = vals[order]
     sc = coords[order]
-    cum = jnp.cumsum(jnp.abs(sv))
-    total = cum[-1]
-    keep = cum <= alpha * total
+    cum = jnp.cumsum(mag)
+    # the total can't round below the kept prefix (alpha=1 keeps all)
+    keep = cum <= alpha * jnp.maximum(jnp.abs(vals).sum(), cum[-1])
     keep = keep.at[0].set(True)  # never emit an empty subvector
-    sv = jnp.where(keep, sv, 0.0)[:out_nnz]
-    sc = jnp.where(keep, sc, 0)[:out_nnz]
+    sv = jnp.where(keep, sv, 0.0)
+    sc = jnp.where(keep, sc, 0)
     pad = out_nnz - sv.shape[0]
     if pad > 0:
         sv = jnp.pad(sv, (0, pad))

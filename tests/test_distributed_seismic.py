@@ -9,7 +9,8 @@ CODE = r"""
 import numpy as np, jax, jax.numpy as jnp
 from repro.data import SyntheticSparseConfig, make_collection
 from repro.core import SeismicConfig, SearchParams
-from repro.core.distributed import build_sharded_index, make_distributed_search
+from repro.core.distributed import (build_sharded_index, make_distributed_search,
+                                    place_on_mesh)
 from repro.core.baselines import exact_search
 from repro.core.oracle import recall_at_k
 from repro.sparse.ops import PaddedSparse
@@ -24,7 +25,7 @@ queries = PaddedSparse(jnp.asarray(queries_np.coords), jnp.asarray(queries_np.va
 mesh = jax.make_mesh((2, 4), ("data", "model"),
                      axis_types=(jax.sharding.AxisType.Auto,) * 2)
 scfg = SeismicConfig(lam=96, beta=8, alpha=0.4, block_cap=24, summary_nnz=24)
-stacked = build_sharded_index(docs, scfg, n_shards=4)
+stacked = place_on_mesh(build_sharded_index(docs, scfg, n_shards=4), mesh)
 p = SearchParams(k=10, cut=8, block_budget=32, policy="adaptive")
 search = make_distributed_search(mesh, p, doc_axes=("model",), data_axis="data")
 with jax.set_mesh(mesh):
@@ -52,3 +53,45 @@ print("OK distributed")
 def test_distributed_search_8dev():
     out = run_with_devices(CODE, n_devices=8)
     assert "OK distributed" in out
+
+
+PLACEMENT = r"""
+import numpy as np, jax, jax.numpy as jnp
+from repro.data import SyntheticSparseConfig, make_collection
+from repro.core import SeismicConfig, SearchParams
+from repro.core.distributed import build_sharded_index, shard_views
+from repro.serve import ReplicaSeismicServer
+
+devs = jax.devices()
+assert len(devs) == 4
+cfg = SyntheticSparseConfig(dim=256, n_docs=512, n_queries=8, doc_nnz=24,
+                            query_nnz=8, n_topics=8, topic_coords=64, seed=2)
+docs_np, queries_np, _ = make_collection(cfg)
+scfg = SeismicConfig(lam=64, beta=4, alpha=0.4, block_cap=16, summary_nnz=16)
+stacked = build_sharded_index(docs_np, scfg, n_shards=4, list_chunk=16)
+for leaf in jax.tree.leaves(stacked):
+    assert leaf.shape[0] == 4
+    for sh in leaf.addressable_shards:       # shard s on device s, alone
+        s = sh.index[0].start or 0
+        assert sh.data.shape[0] == 1 and sh.device == devs[s], (s, sh.device)
+for s, view in enumerate(shard_views(stacked)):
+    for leaf in jax.tree.leaves(view):
+        assert leaf.devices() == {devs[s]}
+server = ReplicaSeismicServer(stacked, SearchParams(k=5, cut=4,
+                              block_budget=8), mode="shard", max_batch=8,
+                              query_nnz=8, n_docs=cfg.n_docs)
+for rid, (view, _) in enumerate(server._replicas):
+    assert {d for x in jax.tree.leaves(view) for d in x.devices()} \
+        == {devs[rid]}
+with server:
+    res = server.search(jax.tree.map(jnp.asarray, queries_np))
+assert (res.ids >= 0).all()
+print("OK placement")
+"""
+
+
+def test_one_shard_per_device_4dev():
+    """build_sharded_index builds shard s on device s, and the shard-mode
+    replica keeps it there."""
+    out = run_with_devices(PLACEMENT, n_devices=4)
+    assert "OK placement" in out
