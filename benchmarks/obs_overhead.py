@@ -1,7 +1,7 @@
-"""Observability overhead gate: full instrumentation — tracing, device
-accounting, AND shadow-oracle quality auditing at its default cadence —
-must stay within <5% p50 request latency and <3% QPS of the
-uninstrumented server.
+"""Observability overhead gate: full instrumentation — tracing AND
+shadow-oracle quality auditing at its default cadence — must stay
+within <5% p50 request latency and <3% QPS of the uninstrumented
+server.
 
 Two measurements, one gate:
 
@@ -12,8 +12,8 @@ paths themselves, on the real index and launch shapes:
     span assembly + ``end_trace``, exactly the calls the batcher makes
     per request (all on the request's critical path);
   * the staged-launch delta — ``run_pipeline_staged`` (with span
-    collection and ``DeviceAccounting.observe``, the full sampled
-    path) minus the fused ``search_pipeline``, amortized by the
+    collection, the full sampled path) minus the fused
+    ``search_pipeline``, amortized by the
     default ``stage_sample_every`` since only every Nth launch pays it;
   * the audit hot-path cost — ``ShadowAuditor.plan`` runs on every
     launch; ``feed`` (row copies + a bounded, non-blocking enqueue)
@@ -51,7 +51,6 @@ from benchmarks.common import row
 from repro.core import SeismicConfig, build_index
 from repro.data import SyntheticSparseConfig, make_collection
 from repro.obs import Observability, ShadowAuditor, Tracer
-from repro.obs.device import DeviceAccounting
 from repro.obs.registry import MetricsRegistry
 from repro.retrieval import SearchParams, search_pipeline
 from repro.retrieval.pipeline import run_pipeline_staged, stage_fns
@@ -129,13 +128,12 @@ def _launch_us(fn, iters: int = 12) -> float:
 
 def _staged_delta_us(idx, p, width: int, nnz: int) -> float:
     """Extra wall time of one fully-instrumented staged launch (span
-    collection + stage-span assembly + device accounting) over the
-    fused launch it replaces."""
+    collection + stage-span assembly) over the fused launch it
+    replaces."""
     coords = jnp.zeros((width, nnz), jnp.int32)
     vals = jnp.zeros((width, nnz), jnp.float32)
     q = PaddedSparse(coords, vals, idx.dim)
     fns = stage_fns(idx, p)
-    device = DeviceAccounting(idx, p, MetricsRegistry())
     tracer = Tracer()
 
     def staged():
@@ -147,8 +145,6 @@ def _staged_delta_us(idx, p, width: int, nnz: int) -> float:
         tr = tracer.start_trace("launch", 0.0)
         attach_stage_spans(tracer, tr, tr.root, triples)
         tracer.end_trace(tr, 1.0)
-        device.observe({n: b - a for n, a, b in triples}, width,
-                       cand=probed.get("cand"))
         return out
 
     def fused():

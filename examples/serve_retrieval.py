@@ -179,7 +179,7 @@ def observability_demo(queries, index):
     for line in metrics.splitlines():
         if line.startswith(("seismic_cache_hit_rate",
                             "seismic_docs_evaluated_mean",
-                            "seismic_stage_modeled_bytes_per_query")):
+                            "seismic_events_total{event=\"compilations\"}")):
             print("     " + line)
     print("   -- metric snapshot (excerpt) --")
     snap = {k: v for k, v in obs.registry.snapshot().items()

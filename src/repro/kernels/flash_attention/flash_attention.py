@@ -96,5 +96,6 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((tile_q,), jnp.float32),       # running denom
             pltpu.VMEM((tile_q, d), jnp.float32),     # accumulator
         ],
+        name="flash_attention",
         interpret=interpret,
     )(q, k, v)

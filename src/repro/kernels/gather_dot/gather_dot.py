@@ -90,6 +90,7 @@ def gather_dot_batch_pallas(q_coords: jax.Array, q_vals: jax.Array,
         in_specs=in_specs,
         out_specs=sz_spec,
         out_shape=jax.ShapeDtypeStruct((qn, n), jnp.float32),
+        name="gather_dot_batch",
         interpret=interpret,
     )(*args)
 
@@ -201,5 +202,6 @@ def gather_dot_cand_pallas(q_dense: jax.Array, cand: jax.Array,
         in_specs=in_specs,
         out_specs=tile_spec,
         out_shape=jax.ShapeDtypeStruct((qn, c), q_dense.dtype),
+        name="gather_dot_cand",
         interpret=interpret,
     )(*args)

@@ -38,9 +38,9 @@ _TILE_N = 128   # minimum aligned tile (lane width) — chooser floor
 def cand_tile_choice(qn: int, c: int, nnz: int, *, quant: bool,
                      dim: int) -> TileChoice:
     """THE tile choice of the candidate-driven kernel for a [qn, c]
-    launch — one definition shared by ``gather_dot_cand_batch``, the
-    microbench/throughput reports, and the obs device accounting, so
-    every ``cand_tiles_processed`` mirror evaluates the kernel's
+    launch — one definition shared by ``gather_dot_cand_batch`` and
+    the microbench/throughput reports, so every
+    ``cand_tiles_processed`` mirror evaluates the kernel's
     actual tiling (the +4 charges the in-kernel candidate-id column)."""
     return choose_tiles(qn, c,
                         row_bytes=gather_row_bytes(nnz, quant=quant) + 4,

@@ -125,5 +125,6 @@ def refine_round_pallas(ids: jax.Array, scored: jax.Array,
             jax.ShapeDtypeStruct((qn, c_out), jnp.int32),
             jax.ShapeDtypeStruct((qn, c_out), q_dense.dtype),
         ),
+        name="refine_round",
         interpret=interpret,
     )(*args)

@@ -103,6 +103,7 @@ def router_flat_pallas(lists: jax.Array, q_dense: jax.Array,
         ],
         out_specs=pl.BlockSpec((tile_q, cut * nb), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((qn, cut * nb), q_dense.dtype),
+        name="router_flat",
         interpret=interpret,
     )(lists, q_dense, sum_coords, sum_q, sum_scale, sum_zero, block_len)
 
@@ -197,6 +198,7 @@ def router_hier_pallas(lists: jax.Array, q_dense: jax.Array,
             jax.ShapeDtypeStruct((qn, m * fanout), q_dense.dtype),
             jax.ShapeDtypeStruct((qn, m * fanout), jnp.int32),
         ),
+        name="router_hier",
         interpret=interpret,
     )(lists, q_dense, sup_coords, sup_q, sup_scale, sup_zero,
       sum_coords, sum_q, sum_scale, sum_zero, block_len)

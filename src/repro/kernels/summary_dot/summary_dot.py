@@ -79,6 +79,7 @@ def summary_dot_batch_pallas(q_coords: jax.Array, q_vals: jax.Array,
         ],
         out_specs=pl.BlockSpec((tile_q, tile_l), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((qn, l), jnp.float32),
+        name="summary_dot_batch",
         interpret=interpret,
     )(q_coords, q_vals, sum_coords, sum_q, sum_scale, sum_zero)
 

@@ -6,8 +6,8 @@
                 histograms) — the one sink every exporter scrapes
     exporters   Prometheus text exposition + stdlib HTTP endpoint +
                 JSONL snapshot writer
-    device      achieved-vs-modeled HBM bandwidth per stage per fuse
-                level (workmodel bytes / measured stage seconds)
+    phase       host phases of a serving thread: a ``seismic.<name>``
+                profiler annotation plus a latency histogram
     report      `python -m repro.obs.report` snapshot + slowest-trace
                 tables
 
@@ -29,10 +29,10 @@ from __future__ import annotations
 
 import dataclasses
 
-from repro.obs.device import DeviceAccounting
 from repro.obs.exporters import (ObsHTTPServer, parse_prometheus_text,
                                  prometheus_text, start_exporter,
                                  write_jsonl_snapshot)
+from repro.obs.phase import phase
 from repro.obs.quality import (FUNNEL_STAGES, ShadowAuditor, per_query_recall,
                                recall_at_k, sample_stats, wilson_interval)
 from repro.obs.registry import (Counter, Family, Gauge, Histogram,
@@ -75,7 +75,7 @@ __all__ = [
     "validate_trace",
     "prometheus_text", "parse_prometheus_text", "write_jsonl_snapshot",
     "ObsHTTPServer", "start_exporter",
-    "DeviceAccounting",
+    "phase",
     "ShadowAuditor", "recall_at_k", "per_query_recall", "wilson_interval",
     "sample_stats", "FUNNEL_STAGES",
 ]

@@ -42,16 +42,28 @@ def run_pipeline(index: SeismicIndex, q_coords: jax.Array,
 
     Returns (scores [Q, k], ids [Q, k] with -1 padding,
     docs_evaluated [Q]). Traceable: safe inside jit / shard_map.
+
+    Each stage runs under a ``jax.named_scope`` of its ``STAGES`` name,
+    so every op of the compiled program carries it in its ``op_name``
+    metadata (``jit(search_pipeline)/scorer/...``); the scopes change
+    nothing else in the program.
     """
     select = get_selector(p.policy)                 # static under jit
-    q_dense, lists, _ = prep_queries(q_coords, q_vals, index.dim, p.cut)
-    q = _sparse_query(index, q_coords, q_vals)
-    batch = route_batch(index, q_dense, lists, p, q)
-    sel = select(index, batch, p)
-    cand, scores = score_selection(index, batch, sel, p.use_kernel,
-                                   fuse_level=p.fuse_level)
-    top_s, top_ids, ev = merge_topk(cand, scores, p.k, index.n_docs)
-    return refine_batch(index, q_dense, top_s, top_ids, ev, p, q)
+    with jax.named_scope("prep"):
+        q_dense, lists, _ = prep_queries(q_coords, q_vals, index.dim,
+                                         p.cut)
+        q = _sparse_query(index, q_coords, q_vals)
+    with jax.named_scope("router"):
+        batch = route_batch(index, q_dense, lists, p, q)
+    with jax.named_scope("selector"):
+        sel = select(index, batch, p)
+    with jax.named_scope("scorer"):
+        cand, scores = score_selection(index, batch, sel, p.use_kernel,
+                                       fuse_level=p.fuse_level)
+    with jax.named_scope("merge"):
+        top_s, top_ids, ev = merge_topk(cand, scores, p.k, index.n_docs)
+    with jax.named_scope("refine"):
+        return refine_batch(index, q_dense, top_s, top_ids, ev, p, q)
 
 
 def _sparse_query(index: SeismicIndex, q_coords, q_vals) -> PaddedSparse:
@@ -127,8 +139,8 @@ def run_pipeline_staged(index: SeismicIndex, q_coords: jax.Array,
     ``refine_round_<j>`` intervals are reported to ``span_cb`` (nested
     inside the ``refine`` interval) — identical results, one extra jit
     boundary per round. ``probe(name, value)`` exposes chosen
-    intermediates (``("cand", scorer candidate ids)``) to device
-    accounting without changing any dataflow; with ``audit`` the probe
+    intermediates (``("cand", scorer candidate ids)``) to the caller
+    without changing any dataflow; with ``audit`` the probe
     additionally receives the per-stage membership captures the
     quality-plane loss funnel attributes misses from — ``lists``
     (probed coordinates), ``router_r`` (flat block summary scores,

@@ -12,9 +12,8 @@ staged retrieval pipeline (see README.md in this package).
                 (plain-dict export shape unchanged)
 
 Pass ``obs=repro.obs.Observability.create()`` to either server for
-request tracing, the serving gauges, sampled per-stage spans, and
-device accounting — one registry scraped by the ``repro.obs``
-exporters. See ``src/repro/obs/README.md``.
+request tracing, the serving gauges and sampled per-stage spans —
+one registry scraped by the ``repro.obs`` exporters. See ``src/repro/obs/README.md``.
 """
 from repro.serve.balancer import StageTimingBalancer
 from repro.serve.batcher import AsyncSeismicServer, ServeResult

@@ -24,10 +24,20 @@ root, ``queue_wait`` and ``launch`` children, and (on every
 per-``refine_round_<j>`` grandchildren, recorded into the tracer's
 ring buffer and exportable as Chrome trace-event JSON. The registry
 gains serving gauges (cache hit-rate, shed/reject rate, deadline-miss
-rate, per-width occupancy, tuned-policy drift) and, via
-:class:`repro.obs.device.DeviceAccounting`, achieved-vs-modeled HBM
-bytes per stage per fuse level on every sampled (staged) launch. See
+rate, per-width occupancy, tuned-policy drift). See
 ``src/repro/obs/README.md`` for the span model and metric names.
+
+Host phases (always on, ``repro.obs.phase``): the batcher thread runs
+each launch as ``batch_wait`` (the whole ``next_batch`` call: the wait
+on an empty queue and the deadline hold), ``pack`` (width, counters,
+the fixed-shape arrays), ``dispatch`` (the ``search_pipeline`` call,
+with the host-to-device put of the batch), ``device_wait`` (its
+``block_until_ready``), ``to_host`` (the outputs to numpy) and
+``fulfil`` (launch accounting and the futures). Each is a
+``seismic.<phase>`` annotation in the profiler's host plane and a
+``seismic_latency_seconds{span=<phase>}`` histogram, one record per
+launch. The telemetry's ``compilations`` counter counts the process's
+backend compilations once the server has warmed up (``start``).
 
 The synchronous ``SeismicServer`` facade in ``engine`` remains the
 simple offline-batch path; this class is the serving path every
@@ -46,6 +56,7 @@ import jax.numpy as jnp
 
 from repro.core.types import SeismicIndex
 from repro.graph.refine import validate_refine_params
+from repro.obs.phase import phase
 from repro.retrieval import SearchParams, search_pipeline
 from repro.retrieval.pipeline import run_pipeline_staged, stage_fns
 from repro.serve.cache import LRUCache, fingerprint_candidates
@@ -116,10 +127,9 @@ class AsyncSeismicServer:
                   attached prefer its sampled stage tracing instead).
     obs           an ``repro.obs.Observability`` bundle: enables
                   request tracing, the serving gauges, and sampled
-                  staged launches with device accounting. When given
-                  and ``telemetry`` is not, the telemetry facade
-                  writes into the bundle's registry so one scrape
-                  sees everything.
+                  staged launches. When given and ``telemetry`` is
+                  not, the telemetry facade writes into the bundle's
+                  registry so one scrape sees everything.
     auditor       a ``repro.obs.ShadowAuditor`` (defaults to
                   ``obs.auditor``): every ``audit_sample_every``-th
                   served request is copied off the hot path for
@@ -192,11 +202,6 @@ class AsyncSeismicServer:
         staged_wanted = stage_timing or self.auditor is not None or (
             obs is not None and obs.stage_sample_every > 0)
         self._fns = stage_fns(index, params) if staged_wanted else None
-        self._device = None
-        if self._fns is not None:
-            from repro.obs.device import DeviceAccounting
-            self._device = DeviceAccounting(index, params,
-                                            self.telemetry.registry)
         # launch counters are shared by every thread that may dispatch
         # (one worker here; N replica workers in ReplicaSeismicServer)
         self._stats_lock = threading.Lock()
@@ -275,6 +280,7 @@ class AsyncSeismicServer:
                                "— build a new AsyncSeismicServer")
         if warmup:
             self.warmup()
+        self.telemetry.count_compilations()
         self._thread = threading.Thread(target=self._worker,
                                         name="seismic-batcher",
                                         daemon=True)
@@ -343,15 +349,10 @@ class AsyncSeismicServer:
         from repro.tune.policy import validate_tuned_index
         validate_tuned_index(index)
         fns = stage_fns(index, params) if self._fns is not None else None
-        device = self._device
-        if fns is not None:
-            from repro.obs.device import DeviceAccounting
-            device = DeviceAccounting(index, params,
-                                      self.telemetry.registry)
         if warmup:
             self._warmup_for(index, params, fns)
         with self._swap_lock:
-            self._publish_swap(index, params, fns, device)
+            self._publish_swap(index, params, fns)
             epoch = self.epoch
         # re-derive gauges bound to the served pair (tuned-policy drift
         # targets, cache hit-rate closures): families are idempotent and
@@ -360,13 +361,12 @@ class AsyncSeismicServer:
         self.telemetry.inc("swaps")
         return epoch
 
-    def _publish_swap(self, index, params, fns, device) -> None:
+    def _publish_swap(self, index, params, fns) -> None:
         """Swap commit point; runs under ``_swap_lock``. Subclasses
         extend it to keep their mirrors in step (replica server)."""
         self.index = index
         self.params = params
         self._fns = fns
-        self._device = device
         self.epoch += 1
 
     def apply_mutation(self, mutable, mutate_fn=None, *,
@@ -486,9 +486,17 @@ class AsyncSeismicServer:
 
     # ---------------------------------------------------------- worker
 
+    def _next_batch(self) -> list[Request] | None:
+        """``queue.next_batch`` as the ``batch_wait`` phase; the
+        shutdown call that returns ``None`` is not recorded."""
+        with phase(self.telemetry, "batch_wait") as wait:
+            batch = self.queue.next_batch(self.max_batch)
+            wait.keep = batch is not None
+        return batch
+
     def _worker(self) -> None:
         while True:
-            batch = self.queue.next_batch(self.max_batch)
+            batch = self._next_batch()
             if batch is None:
                 return
             try:
@@ -555,37 +563,45 @@ class AsyncSeismicServer:
         """One pipeline execution against ``index``; returns host arrays
         plus wall-time bounds and (staged only) per-stage span triples.
 
-        ``delay_s`` injects artificial per-launch latency INSIDE the
-        timed window (replica benchmarks / balancer tests: the EWMA
-        must see it). ``audit`` (staged only) additionally probes the
-        funnel's membership captures for the shadow auditor."""
+        Runs as the ``dispatch``, ``device_wait`` and ``to_host``
+        phases; the staged path blocks stage by stage inside
+        ``dispatch``. ``delay_s`` injects artificial per-launch latency
+        INSIDE the timed window (replica benchmarks / balancer tests:
+        the EWMA must see it). ``audit`` (staged only) additionally
+        probes the funnel's membership captures for the shadow
+        auditor."""
         tel = self.telemetry
         p = self.params if params is None else params
         triples: list[tuple[str, float, float]] = []
         probed: dict[str, object] = {}
         t0 = time.monotonic()
-        if delay_s > 0.0:
-            time.sleep(delay_s)
-        if staged:
-            scores, ids, ev = run_pipeline_staged(
-                index, jnp.asarray(coords), jnp.asarray(vals),
-                p, fns=fns,
-                record=lambda s, dt: tel.record_latency(f"stage_{s}", dt),
-                span_cb=lambda name, a, b: triples.append((name, a, b)),
-                split_refine=True, probe=probed.__setitem__,
-                audit=audit)
-        else:
-            scores, ids, ev = jax.block_until_ready(search_pipeline(
-                index,
-                PaddedSparse(jnp.asarray(coords), jnp.asarray(vals),
-                             index.dim),
-                p))
+        with phase(tel, "dispatch"):
+            if delay_s > 0.0:
+                time.sleep(delay_s)
+            if staged:
+                out = run_pipeline_staged(
+                    index, jnp.asarray(coords), jnp.asarray(vals),
+                    p, fns=fns,
+                    record=lambda s, dt: tel.record_latency(f"stage_{s}",
+                                                            dt),
+                    span_cb=lambda name, a, b: triples.append((name, a, b)),
+                    split_refine=True, probe=probed.__setitem__,
+                    audit=audit)
+            else:
+                out = search_pipeline(
+                    index,
+                    PaddedSparse(jnp.asarray(coords), jnp.asarray(vals),
+                                 index.dim),
+                    p)
+        with phase(tel, "device_wait"):
+            scores, ids, ev = jax.block_until_ready(out)
         t1 = time.monotonic()
-        return (np.asarray(ids), np.asarray(scores), np.asarray(ev),
-                t0, t1, triples, probed)
+        with phase(tel, "to_host"):
+            ids, scores, ev = np.asarray(ids), np.asarray(scores), \
+                np.asarray(ev)
+        return ids, scores, ev, t0, t1, triples, probed
 
-    def _account(self, n: int, width: int, ev: np.ndarray, staged: bool,
-                 triples, probed) -> None:
+    def _account(self, n: int, width: int, ev: np.ndarray) -> None:
         """Post-execution telemetry shared by every dispatch path."""
         tel = self.telemetry
         tel.inc("batches")
@@ -600,10 +616,6 @@ class AsyncSeismicServer:
             ev_mean = self._ev_sum / self._ev_n
         self._width_occ.labels(str(width)).set(occ)
         self._ev_mean.set(ev_mean)
-        if staged and self._device is not None:
-            stage_seconds = {name: b - a for name, a, b in triples}
-            self._device.observe(stage_seconds, width,
-                                 cand=probed.get("cand"))
 
     def _launch(self, batch: list[Request], *, index=None, fns=None,
                 delay_s: float = 0.0, span_attrs: dict | None = None,
@@ -617,27 +629,28 @@ class AsyncSeismicServer:
         launch_seconds, stage_seconds)`` feeds the balancer's EWMA."""
         tel = self.telemetry
         n = len(batch)
-        width = self._pick_width(n)
-        tel.inc(f"launch_width_{width}")
-        tel.inc("dispatched", n)
-        seq = self._next_seq()
-        audit_rows = self.auditor.plan(n) if self.auditor is not None \
-            else ()
-        # one atomic snapshot of the serving generation: a concurrent
-        # swap_index can never tear an old index against new stage fns
-        # or params inside a single launch
-        with self._swap_lock:
-            if index is None:
-                index = self.index
-            if fns is None:
-                fns = self._fns
-            params = self.params
-        have_fns = fns is not None
-        capture = bool(audit_rows) and have_fns
-        staged = self.stage_timing or capture or (
-            have_fns
-            and self.obs is not None and self.obs.sample_stages(seq))
-        coords, vals = self._pack(batch, width)
+        with phase(tel, "pack"):
+            width = self._pick_width(n)
+            tel.inc(f"launch_width_{width}")
+            tel.inc("dispatched", n)
+            seq = self._next_seq()
+            audit_rows = self.auditor.plan(n) \
+                if self.auditor is not None else ()
+            # one atomic snapshot of the serving generation: a
+            # concurrent swap_index can never tear an old index against
+            # new stage fns or params inside a single launch
+            with self._swap_lock:
+                if index is None:
+                    index = self.index
+                if fns is None:
+                    fns = self._fns
+                params = self.params
+            have_fns = fns is not None
+            capture = bool(audit_rows) and have_fns
+            staged = self.stage_timing or capture or (
+                have_fns
+                and self.obs is not None and self.obs.sample_stages(seq))
+            coords, vals = self._pack(batch, width)
         dispatch_t = time.monotonic()
         ids, scores, ev, t0, t1, triples, probed = self._execute(
             index, fns, coords, vals, staged, delay_s, audit=capture,
@@ -646,7 +659,6 @@ class AsyncSeismicServer:
         if on_timing is not None:
             on_timing(t1 - t0,
                       {name: b - a for name, a, b in triples})
-        self._account(n, width, ev, staged, triples, probed)
         audit_span = None
         if audit_rows:
             a0 = time.monotonic()
@@ -655,10 +667,12 @@ class AsyncSeismicServer:
                                   captures=probed if capture else None,
                                   row=i)
             audit_span = (a0, time.monotonic())
-        self._fulfil(batch, ids, scores, ev, dispatch_t=dispatch_t,
-                     t1=t1, width=width, seq=seq, staged=staged,
-                     triples=triples, span_attrs=span_attrs,
-                     audit_span=audit_span)
+        with phase(tel, "fulfil"):
+            self._account(n, width, ev)
+            self._fulfil(batch, ids, scores, ev, dispatch_t=dispatch_t,
+                         t1=t1, width=width, seq=seq, staged=staged,
+                         triples=triples, span_attrs=span_attrs,
+                         audit_span=audit_span)
 
     def _fulfil(self, batch: list[Request], ids: np.ndarray,
                 scores: np.ndarray, ev: np.ndarray, *, dispatch_t: float,

@@ -24,6 +24,7 @@ from repro.configs.base import TransformerConfig
 from repro.core.types import SeismicIndex
 from repro.retrieval import SearchParams, search_pipeline
 from repro.models.transformer import lm
+from repro.obs.phase import phase
 from repro.serve.telemetry import ServerTelemetry
 from repro.sparse.ops import PaddedSparse
 
@@ -95,16 +96,11 @@ class SeismicServer:
         self.auditor = auditor if auditor is not None \
             else getattr(obs, "auditor", None)
         self._fns = None
-        self._device = None
         need_staged = self.auditor is not None or (
             obs is not None and obs.stage_sample_every > 0)
         if need_staged:
             from repro.retrieval.pipeline import stage_fns
             self._fns = stage_fns(index, params)
-        if obs is not None and obs.stage_sample_every > 0:
-            from repro.obs.device import DeviceAccounting
-            self._device = DeviceAccounting(index, params,
-                                            self.telemetry.registry)
         self._launch_seq = 0
         # serving generation; bumped on every swap_index (no result
         # cache here, but callers key their own memoization on it)
@@ -130,10 +126,6 @@ class SeismicServer:
         if self._fns is not None:
             from repro.retrieval.pipeline import stage_fns
             self._fns = stage_fns(index, params)
-        if self._device is not None:
-            from repro.obs.device import DeviceAccounting
-            self._device = DeviceAccounting(index, params,
-                                            self.telemetry.registry)
         self.index = index
         self.params = params
         self.epoch += 1
@@ -151,8 +143,8 @@ class SeismicServer:
                        audit_rows: tuple[int, ...] = ()):
         """One sampled (or audited) chunk through the staged pipeline:
         emits a ``launch`` trace with per-stage (and per-refine-round)
-        child spans, feeds device accounting, and feeds the shadow
-        auditor the planned rows. Bit-exact with the fused path."""
+        child spans and feeds the shadow auditor the planned rows.
+        Bit-exact with the fused path."""
         from repro.retrieval.pipeline import run_pipeline_staged
         from repro.serve.batcher import attach_stage_spans
         tracer = self.obs.tracer if self.obs is not None else None
@@ -188,13 +180,16 @@ class SeismicServer:
                 tracer.add_span(tr, "audit", a_span[0], a_span[1])
             tracer.end_trace(tr, a_span[1] if a_span is not None else t1,
                              status="done")
-        if self._device is not None:
-            stage_seconds = {name: b - a for name, a, b in triples}
-            self._device.observe(stage_seconds, chunk.coords.shape[0],
-                                 cand=probed.get("cand"))
         return out, t1 - t0
 
     def search(self, queries: PaddedSparse) -> RetrievalResult:
+        """Top-k of every row of ``queries``, in ``max_batch`` chunks.
+
+        Without telemetry the chunks dispatch asynchronously and the
+        device is waited on once, in the final conversion; with it each
+        chunk is timed to its completion as a ``launch``. Each chunk's
+        call is a ``seismic.dispatch`` annotation, the conversion a
+        ``seismic.to_host`` one (histograms when telemetry is on)."""
         q = queries
         n = q.coords.shape[0]
         if n == 0:
@@ -228,20 +223,21 @@ class SeismicServer:
                         min(self.max_batch, n - s))
                 outs.append(out)
                 continue
-            if self.telemetry is None:      # async dispatch, convert at end
-                outs.append(search_pipeline(self.index, chunk, self.params))
-                continue
             t0 = time.perf_counter()
-            out = jax.block_until_ready(
-                search_pipeline(self.index, chunk, self.params))
-            self.telemetry.record_latency(
-                "launch", time.perf_counter() - t0)
-            self.telemetry.inc("batches")
-            self.telemetry.observe_occupancy(min(self.max_batch, n - s))
+            with phase(self.telemetry, "dispatch"):
+                out = search_pipeline(self.index, chunk, self.params)
+            if self.telemetry is not None:
+                out = jax.block_until_ready(out)
+                self.telemetry.record_latency(
+                    "launch", time.perf_counter() - t0)
+                self.telemetry.inc("batches")
+                self.telemetry.observe_occupancy(
+                    min(self.max_batch, n - s))
             outs.append(out)
-        scores = np.concatenate([np.asarray(o[0]) for o in outs])[:n]
-        ids = np.concatenate([np.asarray(o[1]) for o in outs])[:n]
-        ev = np.concatenate([np.asarray(o[2]) for o in outs])[:n]
+        with phase(self.telemetry, "to_host"):
+            scores = np.concatenate([np.asarray(o[0]) for o in outs])[:n]
+            ids = np.concatenate([np.asarray(o[1]) for o in outs])[:n]
+            ev = np.concatenate([np.asarray(o[2]) for o in outs])[:n]
         if self.telemetry is not None:
             self.telemetry.inc("requests", n)
             self.telemetry.inc("served", n)

@@ -192,9 +192,7 @@ class ReplicaSeismicServer(AsyncSeismicServer):
         super().__init__(representative, params, **kw)
         if mode == "shard":
             # shard launches are always fused; drop the staged program
-            # (and its device accounting, which binds one index)
             self._fns = None
-            self._device = None
             self.per_shard = representative.fwd.coords.shape[0]
             self.n_docs = n_docs if n_docs is not None \
                 else n_replicas * self.per_shard
@@ -314,8 +312,8 @@ class ReplicaSeismicServer(AsyncSeismicServer):
 
     # ----------------------------------------------------- index swap
 
-    def _publish_swap(self, index, params, fns, device) -> None:
-        super()._publish_swap(index, params, fns, device)
+    def _publish_swap(self, index, params, fns) -> None:
+        super()._publish_swap(index, params, fns)
         if self.mode == "mirror":
             # republish the mirror list wholesale; replica loops
             # re-read it per item, so the next batch on every replica
@@ -354,7 +352,7 @@ class ReplicaSeismicServer(AsyncSeismicServer):
             self._warmup_shards(replicas, params, nd)
             self._warmup_merge(merge, k)
         with self._swap_lock:
-            self._publish_swap(rep, params, None, None)
+            self._publish_swap(rep, params, None)
             self.per_shard = per_shard
             self.n_docs = nd
             self._merge = merge
@@ -371,7 +369,7 @@ class ReplicaSeismicServer(AsyncSeismicServer):
         replica mailboxes; on shutdown drain, send sentinels, join."""
         try:
             while True:
-                batch = self.queue.next_batch(self.max_batch)
+                batch = self._next_batch()
                 if batch is None:
                     return
                 try:
@@ -475,7 +473,7 @@ class ReplicaSeismicServer(AsyncSeismicServer):
         top_s = np.asarray(top_s)
         t1 = time.monotonic()
         tel.record_latency("launch", t1 - job.t0_min)
-        self._account(n, job.width, ev, False, (), {})
+        self._account(n, job.width, ev)
         # shard-mode audits are recall-only (no funnel captures: shard
         # launches run fused, and memberships are per-shard anyway);
         # the auditor must be built over the FULL corpus index so its

@@ -10,13 +10,14 @@ plain-dict export shape, but every record lands in a
     inc(name, n)              -> seismic_events_total{event=name}
     observe_occupancy(n)      -> seismic_launch_occupancy_total{n_real=n}
     observe_queue_depth(d)    -> seismic_queue_depth / _queue_depth_max
+    count_compilations()      -> seismic_events_total{event=
+                                 "compilations"} from then on
 
 so the same numbers the load benchmarks always consumed as dicts are
 now ALSO scrapeable through the Prometheus / JSONL exporters, with no
 double bookkeeping. Pass a shared registry (e.g. from an
 ``Observability`` bundle) to merge server telemetry with the tracing
-and device-accounting metrics; by default each facade owns a fresh
-one.
+and quality metrics; by default each facade owns a fresh one.
 
 ``Histogram`` re-exports the registry histogram: log-spaced buckets
 with quantile estimates that are monotone in ``p`` and always inside
@@ -26,9 +27,31 @@ its odd ``vmin``/``vmax`` clamping are gone).
 """
 from __future__ import annotations
 
+import threading
+import weakref
+
+import jax.monitoring
+
 from repro.obs.registry import Histogram, MetricsRegistry
 
 __all__ = ["Histogram", "ServerTelemetry"]
+
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+# telemetries counting compilations; one listener, registered on first
+# use, feeds them all (tests build many servers in one process)
+_compile_sinks: "weakref.WeakSet[ServerTelemetry]" = weakref.WeakSet()
+_compile_lock = threading.Lock()
+_listening = False
+
+
+def _on_jax_event(event: str, duration: float, **_) -> None:
+    if event != COMPILE_EVENT:
+        return
+    with _compile_lock:
+        sinks = list(_compile_sinks)
+    for tel in sinks:
+        tel.inc("compilations")
 
 
 class ServerTelemetry:
@@ -36,9 +59,13 @@ class ServerTelemetry:
     (compatibility facade over :class:`repro.obs.MetricsRegistry`).
 
     Latency histograms are keyed by name (``request_e2e``,
-    ``queue_wait``, ``launch``, and ``stage_<name>`` when the server
+    ``queue_wait``, ``launch``, the batcher's host phases
+    ``batch_wait`` / ``pack`` / ``dispatch`` / ``device_wait`` /
+    ``to_host`` / ``fulfil``, and ``stage_<name>`` when the server
     runs the staged timing path); counters count requests / batches /
-    admission events; occupancy is a per-launch integer histogram.
+    admission events, and ``compilations`` once
+    :meth:`count_compilations` was called; occupancy is a per-launch
+    integer histogram.
     """
 
     def __init__(self, registry: MetricsRegistry | None = None):
@@ -47,7 +74,7 @@ class ServerTelemetry:
         self._lat = self.registry.histogram(
             "seismic_latency_seconds",
             "Serving latency by span (request_e2e / queue_wait / "
-            "launch / stage_*)", ("span",))
+            "launch / host phases / stage_*)", ("span",))
         self._events = self.registry.counter(
             "seismic_events_total",
             "Serving events (requests / batches / served / rejected / "
@@ -61,6 +88,20 @@ class ServerTelemetry:
         self._depth_max = self.registry.gauge(
             "seismic_queue_depth_max", "Max observed admission queue "
             "depth").labels()
+
+    def count_compilations(self) -> None:
+        """From now on, count the process's backend compilations (every
+        thread's, not only this server's) as the ``compilations``
+        event; a server calls it once warmed up, so a non-zero count
+        means a shape compiled while it served."""
+        global _listening
+        self._events.labels("compilations")    # reads 0 until one
+        with _compile_lock:
+            if not _listening:
+                jax.monitoring.register_event_duration_secs_listener(
+                    _on_jax_event)
+                _listening = True
+            _compile_sinks.add(self)
 
     def record_latency(self, name: str, seconds: float) -> None:
         self._lat.labels(name).record(seconds)
