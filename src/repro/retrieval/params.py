@@ -26,7 +26,12 @@ class SearchParams:
     probe_budget: int = 8         # stage-1 blocks for the adaptive policy
     threshold_factor: float = 0.75  # global_threshold: keep blocks with
     #                                 summary >= factor * per-query max
-    use_kernel: bool = False      # batched Pallas gather/summary kernels
+    use_kernel: bool | None = None  # query-weight lookup of the unfused
+    #                                 stages: True = the Pallas pair-
+    #                                 match kernels, False = the XLA
+    #                                 gather, None = by backend (kernels
+    #                                 on TPU; kernels.runtime.
+    #                                 default_use_kernel)
     fuse_level: int = 0           # kernel-fusion ladder (execution detail,
     #                               results identical at every level):
     #                               0 = unfused reference path (bit-exact
@@ -71,7 +76,7 @@ class SearchParams:
 
     @classmethod
     def from_tuned(cls, index, target: float, *,
-                   use_kernel: bool = False,
+                   use_kernel: bool | None = None,
                    fuse_level: int = 0) -> "SearchParams":
         """Resolve the cheapest ``TunedPolicy`` persisted on ``index``
         whose MEASURED recall meets ``target`` (a policy tuned for 0.90

@@ -20,7 +20,8 @@ Two routed paths behind ``SearchParams.superblock_fanout``:
   ``cut * n_superblocks + superblock_budget * fanout`` summary dots
   per query (:func:`router_work`).
 
-With ``use_kernel`` both tiers use the batched summary_dot Pallas
+With the kernels on (``use_kernel``; ``None``, the default, turns
+them on on a TPU) both tiers use the batched summary_dot Pallas
 kernel (u8 dequant fused) — the identical kernel, just different
 summary arrays.
 
@@ -41,6 +42,7 @@ from typing import TYPE_CHECKING
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.runtime import default_use_kernel
 from repro.retrieval.params import SearchParams
 from repro.sparse.ops import PaddedSparse
 from repro.sparse.quant import dequantize_u8
@@ -66,7 +68,7 @@ class RoutedBatch:
 
 def _summary_scores(q_dense, sc, sq, scale, zero, use_kernel, q=None):
     """<q, dequant(summary)> over a flat [Q, L, S] summary axis."""
-    if use_kernel:
+    if default_use_kernel(use_kernel):
         from repro.kernels.summary_dot.ops import summary_dot_batch
         return summary_dot_batch(q_dense if q is None else q, sc, sq,
                                  scale, zero)

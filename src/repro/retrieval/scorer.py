@@ -2,10 +2,10 @@
 
 Gathers the member docs of every selected block for the whole batch,
 dedupes candidates per query (sort + neighbor mask), and computes the
-exact inner products against the forward index. With ``use_kernel``
-the batched gather_dot Pallas kernel scores all [Q, C] candidates in
-one launch; a compact (u8) forward index dequantizes inside the
-kernel.
+exact inner products against the forward index. With the kernels on
+(``use_kernel``; ``None``, the default, turns them on on a TPU) the
+batched gather_dot Pallas kernel scores all [Q, C] candidates in one
+launch; a compact (u8) forward index dequantizes inside the kernel.
 
 With ``fuse_level >= 1`` two things change (bit-exact results,
 different execution):
@@ -27,6 +27,7 @@ from typing import TYPE_CHECKING
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.runtime import default_use_kernel
 from repro.retrieval.router import NEG, RoutedBatch
 from repro.retrieval.selector import Selection
 from repro.sparse.ops import PaddedSparse
@@ -127,7 +128,7 @@ def compact_candidates(cand: jax.Array) -> jax.Array:
 
 
 def score_candidates(index: SeismicIndex, q_dense: jax.Array,
-                     cand: jax.Array, use_kernel: bool, *,
+                     cand: jax.Array, use_kernel: bool | None, *,
                      fuse_level: int = 0,
                      q: PaddedSparse | None = None) -> jax.Array:
     """Exact <q, doc> for candidate ids [Q, C] (sentinel -> -inf).
@@ -152,7 +153,7 @@ def score_candidates(index: SeismicIndex, q_dense: jax.Array,
     if quant:
         scale = jnp.take(index.fwd_scale, cand, mode="clip")
         zero = jnp.take(index.fwd_zero, cand, mode="clip")
-    if use_kernel:
+    if default_use_kernel(use_kernel):
         from repro.kernels.gather_dot.ops import gather_dot_batch
         scores = gather_dot_batch(q_dense if q is None else q, c, v,
                                   scale, zero)
@@ -169,7 +170,7 @@ def score_candidates(index: SeismicIndex, q_dense: jax.Array,
 
 
 def score_selection(index: SeismicIndex, batch: RoutedBatch,
-                    sel: Selection, use_kernel: bool, *,
+                    sel: Selection, use_kernel: bool | None, *,
                     fuse_level: int = 0) -> tuple[jax.Array, jax.Array]:
     """Selected blocks -> (cand [Q, B*cap], exact scores [Q, B*cap]).
 

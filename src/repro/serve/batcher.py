@@ -9,13 +9,15 @@ clipped to ``max_batch``): each dispatch picks the smallest compiled
 width covering the coalesced batch, so a lone tail request stops
 paying the full ``max_batch`` of padded pipeline work. Every width is
 compiled at warmup; per-width dispatch counts land in telemetry
-(``launch_width_<w>``). The server then fulfills per-request futures. Around that core sit admission
-control (bounded queue, ``reject`` / ``shed_oldest``), a quantized-
-fingerprint LRU result cache, request coalescing (concurrently
-in-flight requests with identical quantized fingerprints share one
-launch slot — the LRU only catches repeats *after* the first
-completes), and telemetry (per-stage latency when ``stage_timing`` is
-on, queue depth, batch occupancy, cache hit-rate).
+(``launch_width_<w>``), and so do the launches by the query-weight
+lookup their program was compiled with (``lookup_pairs`` /
+``lookup_gather``). The server then fulfills per-request futures.
+Around that core sit admission control (bounded queue, ``reject`` /
+``shed_oldest``), a quantized-fingerprint LRU result cache, request
+coalescing (concurrently in-flight requests with identical quantized
+fingerprints share one launch slot — the LRU only catches repeats
+*after* the first completes), and telemetry (per-stage latency when
+``stage_timing`` is on, queue depth, batch occupancy, cache hit-rate).
 
 Observability (``obs=Observability.create()``): a trace id is minted
 at ``submit`` and every request produces a span tree — ``request``
@@ -56,6 +58,7 @@ import jax.numpy as jnp
 
 from repro.core.types import SeismicIndex
 from repro.graph.refine import validate_refine_params
+from repro.kernels.runtime import default_use_kernel
 from repro.obs.phase import phase
 from repro.retrieval import SearchParams, search_pipeline
 from repro.retrieval.pipeline import run_pipeline_staged, stage_fns
@@ -601,10 +604,16 @@ class AsyncSeismicServer:
                 np.asarray(ev)
         return ids, scores, ev, t0, t1, triples, probed
 
-    def _account(self, n: int, width: int, ev: np.ndarray) -> None:
-        """Post-execution telemetry shared by every dispatch path."""
+    def _account(self, n: int, width: int, ev: np.ndarray,
+                 params: SearchParams) -> None:
+        """Post-execution telemetry shared by every dispatch path; the
+        launch also counts under the query-weight lookup its program
+        was compiled with (``lookup_pairs``: the SMEM pair-match
+        kernels; ``lookup_gather``: the XLA dense-row gather)."""
         tel = self.telemetry
         tel.inc("batches")
+        tel.inc("lookup_pairs" if default_use_kernel(params.use_kernel)
+                else "lookup_gather")
         tel.observe_occupancy(n)
         with self._stats_lock:
             ws = self._width_stats.setdefault(width, [0, 0])
@@ -668,7 +677,7 @@ class AsyncSeismicServer:
                                   row=i)
             audit_span = (a0, time.monotonic())
         with phase(tel, "fulfil"):
-            self._account(n, width, ev)
+            self._account(n, width, ev, params)
             self._fulfil(batch, ids, scores, ev, dispatch_t=dispatch_t,
                          t1=t1, width=width, seq=seq, staged=staged,
                          triples=triples, span_attrs=span_attrs,

@@ -473,7 +473,7 @@ class ReplicaSeismicServer(AsyncSeismicServer):
         top_s = np.asarray(top_s)
         t1 = time.monotonic()
         tel.record_latency("launch", t1 - job.t0_min)
-        self._account(n, job.width, ev)
+        self._account(n, job.width, ev, self.params)
         # shard-mode audits are recall-only (no funnel captures: shard
         # launches run fused, and memberships are per-shard anyway);
         # the auditor must be built over the FULL corpus index so its

@@ -71,7 +71,7 @@ class TunedPolicy:
     sample_fingerprint: str = ""   # order-invariant sample digest
     modeled: bool = False          # True: config-time model, not measured
 
-    def to_params(self, *, use_kernel: bool = False,
+    def to_params(self, *, use_kernel: bool | None = None,
                   fuse_level: int = 0) -> SearchParams:
         """The pipeline params this policy pins — bit-exact: every knob
         is stored on the policy, nothing is re-derived. ``use_kernel``

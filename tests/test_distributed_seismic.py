@@ -95,3 +95,49 @@ def test_one_shard_per_device_4dev():
     replica keeps it there."""
     out = run_with_devices(PLACEMENT, n_devices=4)
     assert "OK placement" in out
+
+
+KERNELS = r"""
+import numpy as np, jax, jax.numpy as jnp
+from repro.data import SyntheticSparseConfig, make_collection
+from repro.core import SeismicConfig, SearchParams
+from repro.core.distributed import (build_sharded_index, make_distributed_search,
+                                    place_on_mesh)
+from repro.sparse.ops import PaddedSparse
+
+assert len(jax.devices()) == 4
+cfg = SyntheticSparseConfig(dim=512, n_docs=1024, n_queries=8, doc_nnz=32,
+                            query_nnz=12, n_topics=16, topic_coords=96, seed=4)
+docs_np, queries_np, _ = make_collection(cfg)
+docs = PaddedSparse(jnp.asarray(docs_np.coords), jnp.asarray(docs_np.vals), docs_np.dim)
+mesh = jax.make_mesh((1, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
+scfg = SeismicConfig(lam=96, beta=8, alpha=0.4, block_cap=24, summary_nnz=24)
+stacked = place_on_mesh(build_sharded_index(docs, scfg, n_shards=4), mesh)
+qc, qv = jnp.asarray(queries_np.coords), jnp.asarray(queries_np.vals)
+out = {}
+for use_kernel in (False, True):
+    p = SearchParams(k=10, cut=8, block_budget=32, policy="adaptive",
+                     use_kernel=use_kernel)
+    search = make_distributed_search(mesh, p, doc_axes=("model",),
+                                     data_axis="data")
+    with jax.set_mesh(mesh):
+        text = str(jax.make_jaxpr(search)(stacked, qc, qv))
+        out[use_kernel] = jax.jit(search)(stacked, qc, qv)
+    # the kernels sit inside the shard_map body, and only on request
+    assert ("summary_dot_batch" in text) == use_kernel, use_kernel
+    assert ("gather_dot_batch" in text) == use_kernel, use_kernel
+    assert "shard_map" in text
+np.testing.assert_array_equal(np.asarray(out[True][1]), np.asarray(out[False][1]))
+np.testing.assert_allclose(np.asarray(out[True][0]), np.asarray(out[False][0]),
+                           rtol=1e-6)
+print("OK kernels under shard_map")
+"""
+
+
+def test_distributed_search_traces_kernels_4dev():
+    """The doc-sharded search traces and runs with the pair-match
+    kernels (interpret mode) inside ``shard_map`` and answers as the
+    gather does."""
+    out = run_with_devices(KERNELS, n_devices=4)
+    assert "OK kernels under shard_map" in out

@@ -5,6 +5,8 @@ sentinel-duplicate invariance and k>C clamp edges, and selector
 policies returning fixed shapes under jit. Hypothesis hardens the
 merge properties when installed; the deterministic sweeps run always.
 """
+import dataclasses
+
 import numpy as np
 import pytest
 import jax
@@ -196,6 +198,90 @@ def test_pipeline_kernel_path_matches_jnp(small_index, small_collection):
     np.testing.assert_allclose(np.asarray(s0), np.asarray(s1),
                                rtol=1e-5, atol=1e-5)
     np.testing.assert_array_equal(np.asarray(e0), np.asarray(e1))
+
+
+# --------------------------------------- query-weight lookup by backend
+
+def _jaxpr_text(idx, queries, p) -> str:
+    """The unjitted pipeline's jaxpr, traced afresh (a cached jit trace
+    would not see a monkeypatched backend)."""
+    from repro.retrieval import run_pipeline
+    return str(jax.make_jaxpr(
+        lambda c, v: run_pipeline(idx, c, v, p))(queries.coords,
+                                                 queries.vals))
+
+
+@pytest.mark.parametrize("tpu", [False, True], ids=["cpu", "tpu"])
+@pytest.mark.parametrize("use_kernel", [None, True, False],
+                         ids=["auto", "kernels", "gather"])
+def test_use_kernel_resolves_by_backend(monkeypatch, small_index,
+                                        small_collection, tpu, use_kernel):
+    """``use_kernel=None`` takes the pair-match kernels on a TPU and
+    the XLA gather elsewhere; an explicit True / False holds on both.
+    The router's and the scorer's kernels come and go together."""
+    from repro.kernels import runtime
+    monkeypatch.setattr(runtime, "on_tpu", lambda: tpu)
+    want = tpu if use_kernel is None else use_kernel
+    assert runtime.default_use_kernel(use_kernel) is want
+    idx, _ = small_index
+    _, queries, *_ = small_collection
+    text = _jaxpr_text(idx, queries, SearchParams(
+        k=10, cut=8, block_budget=32, policy="adaptive",
+        use_kernel=use_kernel))
+    assert ("summary_dot_batch" in text) == want
+    assert ("gather_dot_batch" in text) == want
+
+
+def test_search_params_default_to_backend_lookup():
+    """The default and every constructor that derives params leave the
+    lookup to the backend."""
+    from repro.tune.policy import TunedPolicy
+    assert SearchParams().use_kernel is None
+    assert TunedPolicy(target=0.9).to_params().use_kernel is None
+
+
+@pytest.fixture(scope="module")
+def msmarco_width_index():
+    """A cut-down index at the benchmark's widths: vocabulary 30522,
+    doc / query nnz 128 / 48, summary width 96, block_cap 64; lists
+    shortened (lam 256, beta 8) and built only where the queries
+    probe."""
+    from repro.core import SeismicConfig, build_index
+    from repro.data import SyntheticSparseConfig, make_collection
+    from repro.retrieval.prep import probed_lists
+    from repro.sparse.ops import PaddedSparse
+    cfg = SyntheticSparseConfig(dim=30522, n_docs=4096, n_queries=8,
+                                doc_nnz=128, query_nnz=48, n_topics=16,
+                                topic_coords=512, seed=11)
+    docs_np, q_np, _ = make_collection(cfg)
+    docs = PaddedSparse(jnp.asarray(docs_np.coords),
+                        jnp.asarray(docs_np.vals), cfg.dim)
+    queries = PaddedSparse(jnp.asarray(q_np.coords),
+                           jnp.asarray(q_np.vals), cfg.dim)
+    lists = probed_lists(q_np.coords, q_np.vals, cfg.dim, 10)
+    idx = build_index(docs, SeismicConfig(lam=256, beta=8, alpha=0.4,
+                                          block_cap=64, summary_nnz=96,
+                                          fwd_dtype="bfloat16"),
+                      list_chunk=16, lists=lists)
+    return idx, queries
+
+
+@pytest.mark.parametrize("budget", [64, 96], ids=["r90", "r97"])
+def test_kernel_lookup_matches_gather_at_msmarco_widths(
+        msmarco_width_index, budget):
+    """At the benchmark's widths and budgets (adaptive policy) the
+    pair-match kernels (interpret mode) serve what the XLA gather
+    serves: the same ids and docs evaluated, scores to 1e-6."""
+    idx, queries = msmarco_width_index
+    p = SearchParams(k=10, cut=10, block_budget=budget, policy="adaptive")
+    s0, i0, e0 = search_pipeline(idx, queries,
+                                 dataclasses.replace(p, use_kernel=False))
+    s1, i1, e1 = search_pipeline(idx, queries,
+                                 dataclasses.replace(p, use_kernel=True))
+    np.testing.assert_array_equal(np.asarray(i0), np.asarray(i1))
+    np.testing.assert_array_equal(np.asarray(e0), np.asarray(e1))
+    assert (np.asarray(e0) > 256).all()   # candidates of several lists
+    np.testing.assert_allclose(np.asarray(s1), np.asarray(s0), rtol=1e-6)
 
 
 @pytest.mark.parametrize("policy", ["budget", "adaptive",

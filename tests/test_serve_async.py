@@ -160,6 +160,31 @@ def test_launch_width_dispatch_and_telemetry(small_index,
                                    rtol=1e-6)
 
 
+@pytest.mark.parametrize("use_kernel,counted",
+                         [(None, "lookup_gather"), (False, "lookup_gather"),
+                          (True, "lookup_pairs")],
+                         ids=["auto", "gather", "pairs"])
+def test_lookup_counter_one_per_launch(small_index, small_collection,
+                                       use_kernel, counted):
+    """Every launch counts once under the query-weight lookup its
+    program was compiled with; on the CPU the default is the gather."""
+    idx, _ = small_index
+    _, queries, *_ = small_collection
+    srv = AsyncSeismicServer(idx, _params(use_kernel=use_kernel),
+                             max_batch=4, query_nnz=16, launch_widths=(4,),
+                             deadline_s=0.01)
+    with srv:
+        futs = [srv.submit(np.asarray(queries.coords[i]),
+                           np.asarray(queries.vals[i]))
+                for i in range(8)]
+        for f in futs:
+            f.result(30.0)
+    counters = srv.telemetry_export()["counters"]
+    assert counters["batches"] >= 2
+    assert counters[counted] == counters["batches"]
+    assert not ({"lookup_pairs", "lookup_gather"} - {counted}) & set(counters)
+
+
 # -------------------------------------------------- admission control
 
 def test_admission_reject_new(small_index, small_collection):

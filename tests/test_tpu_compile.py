@@ -2,9 +2,10 @@
 
 The unfused kernels of the main path (``summary_dot_batch``,
 ``gather_dot_batch`` plain and u8) must lower through Mosaic at MS MARCO
-widths, under the stable name their ``pallas_call`` gives them, and the jitted serving step (``search_pipeline`` at launch
-width 256, index passed as shapes) must compile for one v5e and fit
-its 16 GiB with the whole index resident.
+widths, under the stable name their ``pallas_call`` gives them, and
+the jitted serving step (``search_pipeline`` at the benchmark's launch
+widths, index passed as shapes) must compile for one v5e, take those
+kernels by default, and fit its 16 GiB with the whole index resident.
 
 The topology is described inside a module fixture, never at import,
 so only the pytest worker that runs this file loads the TPU library.
@@ -64,9 +65,9 @@ def _spec(sharding, shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _queries(sharding):
-    return PaddedSparse(_spec(sharding, (WIDTH, CONFIG.query_nnz), jnp.int32),
-                        _spec(sharding, (WIDTH, CONFIG.query_nnz),
+def _queries(sharding, width=WIDTH):
+    return PaddedSparse(_spec(sharding, (width, CONFIG.query_nnz), jnp.int32),
+                        _spec(sharding, (width, CONFIG.query_nnz),
                               jnp.float32), CONFIG.dim)
 
 
@@ -99,16 +100,29 @@ def test_gather_dot_lowers_for_v5e(one_chip, mosaic, quant):
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("use_kernel", [False, True], ids=["xla", "kernel"])
-def test_serving_step_fits_one_v5e(one_chip, mosaic, use_kernel):
+@pytest.mark.parametrize(
+    "use_kernel,width,budget",
+    [(False, WIDTH, BUDGET), (True, WIDTH, BUDGET),
+     (None, 256, 96), (None, 8, 64)],
+    ids=["xla", "kernel", "default-w256-b96", "default-w8-b64"])
+def test_serving_step_fits_one_v5e(one_chip, mosaic, use_kernel, width,
+                                   budget):
+    """The step at the benchmark cells' shapes (bulk: 256 wide, budget
+    96; online: 8 wide, budget 64) takes the pair-match kernels by
+    default on the chip, and fits."""
     index = jax.tree.map(lambda x: _spec(one_chip, x.shape, x.dtype),
                          index_shape(N_DOCS, CONFIG.dim, CONFIG.doc_nnz,
                                      CONFIG.index))
-    p = SearchParams(k=10, cut=CUT, block_budget=BUDGET,
+    p = SearchParams(k=10, cut=CUT, block_budget=budget,
                      use_kernel=use_kernel, fuse_level=0)
-    compiled = search_pipeline.lower(index, _queries(one_chip),
+    compiled = search_pipeline.lower(index, _queries(one_chip, width),
                                      p).compile()
-    assert ("tpu_custom_call" in compiled.as_text()) == use_kernel
+    text = compiled.as_text()
+    kernels = use_kernel is not False
+    assert ("tpu_custom_call" in text) == kernels
+    for name in ("summary_dot_batch", "gather_dot_batch"):
+        assert (re.search(rf"%{name}(\.\d+)? = ", text) is not None) \
+            == kernels, name
     m = compiled.memory_analysis()
     resident = sum(x.size * x.dtype.itemsize
                    for x in jax.tree.leaves(index))
