@@ -10,6 +10,9 @@ document value.
   corpus, on the device, scanning document chunks with a running top-k
   so that nothing ``[Q, N, nnz]``-shaped exists. Ties keep the lower
   document id.
+* :func:`exact_topk_ranged` — the same answer for a corpus spread over
+  several devices: one contiguous range of documents scanned on each,
+  merged on the host.
 * :func:`pair_scores` — the exact score of given (query, document)
   pairs, on the host in f64.
 """
@@ -55,6 +58,34 @@ def exact_topk(doc_coords: np.ndarray, doc_vals: np.ndarray,
                q_dense: np.ndarray, k: int, device=None):
     """(scores [Q, k] f32, ids [Q, k] int32) of the exact search over
     every document, computed on ``device``."""
+    s, i = _scan_on(doc_coords, doc_vals, q_dense, k, device)
+    return np.asarray(s), np.asarray(i)
+
+
+def exact_topk_ranged(doc_coords: np.ndarray, doc_vals: np.ndarray,
+                      q_dense: np.ndarray, k: int, devices):
+    """:func:`exact_topk` of a corpus too large for one device: range
+    ``r`` of ``len(devices)`` contiguous ranges of documents (``[r * n
+    // R, (r + 1) * n // R)``) is scanned on ``devices[r]``, each range
+    padded on its own, all ranges at once; the ranges' top-k are merged
+    on the host, ties to the lower id, in the corpus's own numbering."""
+    n = doc_coords.shape[0]
+    r = len(devices)
+    bounds = [i * n // r for i in range(r + 1)]
+    parts = [(lo, _scan_on(doc_coords[lo:hi], doc_vals[lo:hi], q_dense, k,
+                           dev))
+             for lo, hi, dev in zip(bounds, bounds[1:], devices)]
+    scores = np.concatenate([np.asarray(s) for _, (s, _) in parts], axis=1)
+    ids = np.concatenate([np.asarray(i) + lo for lo, (_, i) in parts],
+                         axis=1)
+    order = np.lexsort((ids, -scores), axis=1)[:, :k]
+    return (np.take_along_axis(scores, order, axis=1),
+            np.take_along_axis(ids, order, axis=1).astype(np.int32))
+
+
+def _scan_on(doc_coords, doc_vals, q_dense, k: int, device):
+    """Dispatch the chunked scan of ``doc_coords`` on ``device``; the
+    device's (scores, ids), not waited for."""
     n, nnz = doc_coords.shape
     qn = q_dense.shape[0]
     chunk = int(max(8, min(n, CHUNK_BYTES // (nnz * qn * 4))))
@@ -65,9 +96,8 @@ def exact_topk(doc_coords: np.ndarray, doc_vals: np.ndarray,
     vals = np.pad(doc_vals.astype(np.float32),
                   ((0, pad), (0, 0))).reshape(steps, chunk, nnz)
     put = partial(jax.device_put, device=device)
-    s, i = _scan_topk(put(coords), put(vals), put(q_dense.T.copy()),
+    return _scan_topk(put(coords), put(vals), put(q_dense.T.copy()),
                       n=n, k=k)
-    return np.asarray(s), np.asarray(i)
 
 
 @partial(jax.jit, static_argnames=("n", "k"))

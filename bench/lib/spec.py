@@ -79,6 +79,37 @@ def load_config(spec: dict, name: str, root: str = ROOT) -> dict:
     return _read_json(os.path.join(root, config_entry(spec, name)["file"]))
 
 
+def shards(cfg: dict) -> int:
+    """Shards the configuration splits its corpus into (``"layout":
+    {"shards": N}``); 0 where it has no ``layout`` and one chip holds
+    the whole corpus."""
+    layout = cfg.get("layout")
+    if layout is None:
+        return 0
+    n = layout.get("shards") if isinstance(layout, dict) else None
+    if type(n) is not int or n < 1:
+        raise SpecError(f"config {cfg.get('name')!r}: layout.shards must "
+                        f"be a whole number of at least 1, got {n!r}")
+    return n
+
+
+def check_layout(cell: dict, cfg: dict, mix: dict) -> None:
+    """A sharded configuration builds one shard on each of the cell's
+    chips and serves them through ``submit``; refuse a cell that breaks
+    either rule."""
+    n = shards(cfg)
+    if n and n != cell["chips"]:
+        raise SpecError(
+            f"cell {cell['name']!r}: layout.shards {n} of config "
+            f"{cell['config']!r} must equal the cell's chips "
+            f"({cell['chips']}): one shard is built on each chip")
+    if n and mix["api"] != "submit":
+        raise SpecError(
+            f"cell {cell['name']!r}: a sharded corpus is served only "
+            f"through submit (the replica server's shard mode); traffic "
+            f"{cell['traffic']!r} uses {mix['api']!r}")
+
+
 def load_traffic(name: str, bench_dir: str = BENCH_DIR) -> dict:
     return _read_json(os.path.join(bench_dir, "traffic", f"{name}.json"))
 

@@ -52,7 +52,7 @@ def main() -> int:
     devs = run.require_devices(1)
     run.enable_compile_cache()
     for seed in (int(s) for s in args.seeds.split(",")):
-        prep = run.prepare(cfgs[0], mixes[0], seed, devs[0])
+        prep = run.prepare(cfgs[0], mixes[0], seed, devs)
         windows = []
         for cell, cfg, mix in zip(cells, cfgs, mixes):
             server = run.start_server(prep, cfg, mix)
@@ -63,7 +63,7 @@ def main() -> int:
         gc.collect()
         # the reference as configured (bf16 plane), control or not
         ref_cfg = spec.load_config(bench, cells[0]["config"])
-        ref = run.reference_of(prep, ref_cfg, devs[0])
+        ref = run.reference_of(prep, ref_cfg, devs)
         for cell, cfg, w in zip(cells, cfgs, windows):
             checks = run.judge(w, prep, ref, cfg)
             print(json.dumps({

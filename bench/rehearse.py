@@ -50,8 +50,7 @@ def rehearse(cell: dict, cfg: dict, mix: dict, one_chip) -> list[dict]:
         index_shape(c["n_docs"], c["dim"], c["doc_nnz"],
                     SeismicConfig(**cfg["index"])))
     resident = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(index))
-    params = SearchParams(k=s["k"], cut=s["cut"],
-                          block_budget=s["block_budget"])
+    params = SearchParams(**s)
     nnz = cfg["serve"]["query_nnz"]
     rows = []
     for w in widths(cfg, mix):

@@ -10,12 +10,14 @@ The cell (``BENCHMARK.json``) names a configuration
 1. refuses to run without a TPU, or with fewer chips than the cell asks;
 2. set-up, timed as ``setup_s``: draws the corpus and the query pool
    from ``--seed``, builds the index on the chip over the lists the
-   pool probes, starts the server and compiles every launch shape the
-   mix uses;
+   pool probes (with ``"layout": {"shards": N}`` in the configuration,
+   one shard of the corpus on each of the cell's N chips), starts the
+   server and compiles every launch shape the mix uses;
 3. drives the server with the mix for ``--seconds`` (with ``--trace 1``
    under the profiler);
-4. reads the peak device memory, frees the server and the index, and
-   runs the plain reference over the same corpus and queries;
+4. reads the peak device memory (the fullest chip's), frees the server
+   and the index, and runs the plain reference over the same corpus and
+   queries (on a sharded corpus, one range of documents on each chip);
 5. compares every answer of the window with the reference
    (``lib/check.py``) and prints the checks on standard error, and the
    result as one JSON line, last on standard output.
@@ -123,24 +125,41 @@ def build(cfg: dict, doc_coords, doc_vals, lists, dev):
     return jax.block_until_ready(index)
 
 
+def build_sharded(cfg: dict, doc_coords, doc_vals, lists, devs):
+    """The program's sharded build from the host arrays: shard s of
+    ``spec.shards(cfg)`` built on its own device of ``devs``, so that
+    no chip holds the whole corpus."""
+    import jax
+    from repro.core import SeismicConfig
+    from repro.core.distributed import build_sharded_index
+    from repro.sparse.ops import PaddedSparse
+    index = build_sharded_index(
+        PaddedSparse(doc_coords, doc_vals, cfg["corpus"]["dim"]),
+        SeismicConfig(**cfg["index"]), spec.shards(cfg),
+        list_chunk=cfg["build"]["list_chunk"], devices=devs, lists=lists)
+    return jax.block_until_ready(index)
+
+
 def make_server(index, cfg: dict, mix: dict):
     from repro.retrieval import SearchParams
-    s = cfg["search"]
-    params = SearchParams(k=s["k"], cut=s["cut"],
-                          block_budget=s["block_budget"])
-    srv = mix["server"]
+    params = SearchParams(**cfg["search"])
     if mix["api"] == "search":
         from repro.serve import SeismicServer
         return SeismicServer(index, params, max_batch=cfg["serve"]["max_batch"])
-    from repro.serve import AsyncSeismicServer
+    srv = mix["server"]
     widths = srv.get("launch_widths")
-    return AsyncSeismicServer(
-        index, params, max_batch=cfg["serve"]["max_batch"],
-        query_nnz=cfg["serve"]["query_nnz"],
-        launch_widths=None if widths is None else tuple(widths),
-        deadline_s=srv["deadline_ms"] * 1e-3,
-        queue_bound=srv["queue_bound"], cache_size=srv["cache_size"],
-        coalesce=srv["coalesce"])
+    kw = dict(max_batch=cfg["serve"]["max_batch"],
+              query_nnz=cfg["serve"]["query_nnz"],
+              launch_widths=None if widths is None else tuple(widths),
+              deadline_s=srv["deadline_ms"] * 1e-3,
+              queue_bound=srv["queue_bound"], cache_size=srv["cache_size"],
+              coalesce=srv["coalesce"])
+    if spec.shards(cfg):
+        from repro.serve import ReplicaSeismicServer
+        return ReplicaSeismicServer(index, params, mode="shard",
+                                    n_docs=cfg["corpus"]["n_docs"], **kw)
+    from repro.serve import AsyncSeismicServer
+    return AsyncSeismicServer(index, params, **kw)
 
 
 # ----------------------------------------------------------------- window
@@ -203,17 +222,25 @@ def trace_summary(log_dir: str):
 
 # ----------------------------------------------------------------- phases
 
-def prepare(cfg: dict, mix: dict, seed: int, dev) -> dict:
-    """Data from ``seed`` and the index built on ``dev`` over the lists
-    the query pool probes; with the per-query live-block counts and row
-    sizes the necessary-bytes metric needs."""
+def prepare(cfg: dict, mix: dict, seed: int, devs) -> dict:
+    """Data from ``seed`` and the index built over the lists the query
+    pool probes, on ``devs[0]`` or, for a sharded configuration, one
+    shard on each of ``devs``; with the per-query live-block counts and
+    row sizes the necessary-bytes metric needs."""
     t0 = time.perf_counter()
     doc_coords, doc_vals, q_coords, q_vals = make_data(cfg, mix, seed)
     t_data = time.perf_counter() - t0
     probed_q = probed(q_coords, q_vals, cfg["search"]["cut"])
     lists = np.unique(probed_q)
-    index = build(cfg, doc_coords, doc_vals, lists, dev)
-    live_per_list = np.asarray((index.block_len > 0).sum(axis=1))
+    sharded = spec.shards(cfg) > 0
+    if sharded:
+        index = build_sharded(cfg, doc_coords, doc_vals, lists, devs)
+    else:
+        index = build(cfg, doc_coords, doc_vals, lists, devs[0])
+    live_per_list = np.asarray((index.block_len > 0).sum(axis=-1))
+    if sharded:
+        # [shards, lists]: a query routes over every shard's blocks
+        live_per_list = live_per_list.sum(axis=0)
     log(f"bench: data {t_data:.3f} s, build "
         f"{time.perf_counter() - t0 - t_data:.3f} s over {lists.size} lists")
     return dict(doc_coords=doc_coords, doc_vals=doc_vals, q_coords=q_coords,
@@ -279,15 +306,22 @@ def measure(server, prep: dict, cfg: dict, mix: dict, seed: int,
     return w
 
 
-def reference_of(prep: dict, cfg: dict, dev) -> dict:
-    """The plain reference over the pool; run once the index is freed."""
+def reference_of(prep: dict, cfg: dict, devs) -> dict:
+    """The plain reference over the pool, on ``devs[0]`` or, for a
+    sharded configuration, one range of the corpus on each of ``devs``;
+    run once the index is freed."""
     stored_vals = reference.stored(prep["doc_vals"],
                                    cfg["index"]["fwd_dtype"])
     q_dense = reference.dense_queries(prep["q_coords"], prep["q_vals"],
                                       cfg["corpus"]["dim"])
-    exact_scores, exact_ids = reference.exact_topk(
-        prep["doc_coords"], stored_vals, q_dense, cfg["search"]["k"],
-        device=dev)
+    if spec.shards(cfg):
+        exact_scores, exact_ids = reference.exact_topk_ranged(
+            prep["doc_coords"], stored_vals, q_dense, cfg["search"]["k"],
+            devices=devs)
+    else:
+        exact_scores, exact_ids = reference.exact_topk(
+            prep["doc_coords"], stored_vals, q_dense, cfg["search"]["k"],
+            device=devs[0])
     return dict(stored_vals=stored_vals, q_dense=q_dense,
                 exact_scores=exact_scores, exact_ids=exact_ids)
 
@@ -309,10 +343,11 @@ def run_cell(cell: dict, cfg: dict, mix: dict, metric_defs: list,
              chip: dict) -> tuple[dict, list]:
     """One run of ``cell`` on ``devs``; returns (result, checks)."""
     import jax
+    spec.check_layout(cell, cfg, mix)
     dev = devs[0]
     readers = {m["name"]: spec.metric_reader(m["name"]) for m in metric_defs}
     t_setup = time.perf_counter()
-    prep = prepare(cfg, mix, seed, dev)
+    prep = prepare(cfg, mix, seed, devs)
     server = start_server(prep, cfg, mix)
     setup_s = time.perf_counter() - t_setup
     log(f"bench: setup {setup_s:.3f} s")
@@ -321,7 +356,7 @@ def run_cell(cell: dict, cfg: dict, mix: dict, metric_defs: list,
     prep.pop("index")
     gc.collect()
     t_ref = time.perf_counter()
-    checks = judge(w, prep, reference_of(prep, cfg, dev), cfg)
+    checks = judge(w, prep, reference_of(prep, cfg, devs), cfg)
     ref_s = time.perf_counter() - t_ref
 
     done = w["answered"]

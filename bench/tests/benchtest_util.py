@@ -45,17 +45,29 @@ def tiny_cell(name: str):
     return cell, cfg, mix
 
 
+def shard_layout(n: int):
+    """A ``cfg_edit`` that splits the configuration's corpus into ``n``
+    shards (``"layout": {"shards": n}``)."""
+    def edit(cfg):
+        cfg["layout"] = {"shards": n}
+    return edit
+
+
 def run_tiny(name: str, seed: int = 2 ** 33 + 5, seconds: float = 0.5,
-             cfg_edit=None):
+             cfg_edit=None, chips: int | None = None):
     """One run of a cut-down cell on the CPU, past the harness's look
-    for a chip; returns {check name: Check} and the result."""
+    for a chip, on the cell's chips (or ``chips``) of the CPU devices;
+    returns {check name: Check} and the result."""
     import jax
     run = load_run()
     cell, cfg, mix = tiny_cell(name)
+    if chips is not None:
+        cell = dict(cell, chips=chips)
     if cfg_edit is not None:
         cfg_edit(cfg)
     bench = spec.load_benchmark()
     out, checks = run.run_cell(
         cell, cfg, mix, spec.cell_metrics(bench, name, "end_to_end"), seed,
-        seconds, False, jax.devices()[:1], {"hbm_bytes_per_s": 1e11})
+        seconds, False, jax.devices()[:cell["chips"]],
+        {"hbm_bytes_per_s": 1e11})
     return {c.name: c for c in checks}, out

@@ -36,8 +36,8 @@ def test_online_sweeps_on_a_cut_down_cell(capsys):
     cal = load_calibrate()
     run = load_run()
     _, cfg, mix = tiny_cell("splade-r90.online")
-    prep = run.prepare(cfg, mix, 3, jax.devices()[0])
-    ref = run.reference_of(prep, cfg, jax.devices()[0])
+    prep = run.prepare(cfg, mix, 3, jax.devices()[:1])
+    ref = run.reference_of(prep, cfg, jax.devices()[:1])
     args = (prep["index"], cfg, mix, prep["q_coords"], prep["q_vals"],
             ref["exact_ids"])
     cal.knee_sweep(*args, [100.0, 200.0], 3, 0.3)

@@ -1,9 +1,9 @@
 """The trace reduction: busy time as the union of device operations,
 idle share, the operations that took most time, and the longest idle
 gaps named by the host spans that overlap them. Checked on a small
-hand-written trace with known answers and on a small trace recorded on
-a v5e (``data/tpu_small.xplane.pb``, made by
-``data/record_trace.py``)."""
+hand-written trace with known answers and on small traces recorded on
+one v5e chip and on four (``data/tpu_small.xplane.pb`` and
+``data/tpu_four.xplane.pb``, made by ``data/record_trace.py``)."""
 import os
 
 import pytest
@@ -101,3 +101,20 @@ def test_recorded_v5e_trace():
     assert len(s.idle_gaps) >= 3
     assert all(g[0].split(" / ")[0] in ("bench.call", "bench.sleep")
                for g in s.idle_gaps[:3])
+
+
+def test_recorded_four_chip_v5e_trace():
+    """The same program on each of four chips at once, three calls with
+    2 ms of sleep after each (``record_trace.py --chips 4``): every chip
+    is a device plane, busy time is the mean over them, and an
+    operation's time is summed over them."""
+    s = xplane.reduce(xplane.load(os.path.join(DATA, "tpu_four.xplane.pb")))
+    # the chips' host-interface and misc planes and the Megascale plane
+    # are not chips
+    assert s.n_devices == 4
+    assert s.window_s == pytest.approx(13.057864e-3)
+    assert 2 * 11.7e-6 <= s.busy_s <= 3 * 11.9e-6
+    name, seconds = s.top_ops[0]
+    assert name.startswith("%fusion = f32[1024]")
+    assert seconds == pytest.approx(4 * s.busy_s, rel=0.01)
+    assert s.idle_share > 0.99
