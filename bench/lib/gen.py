@@ -22,19 +22,31 @@ outside both topics are never drawn (their weight was e^-30 per
 coordinate in the dense formulation this replaces), so a row costs
 O(2 * topic_coords) instead of O(dim). Rows are drawn in fixed-size
 chunks, each from its own generator seeded by (seed, stream, chunk), at
-most ``MAX_THREADS`` chunks at a time: host memory is bounded by the
-chunk, and the result does not depend on how many threads draw them.
+most ``MAX_THREADS`` chunks at a time, so the result does not depend on
+how many threads draw them.
+
+Host footprint: a chunk is worked ``ROW_BLOCK`` rows at a time, so no
+temporary exceeds ``ROW_BLOCK`` x 2 * topic_coords float64 (3 MB at MS
+MARCO widths), and each worker keeps one ``[CHUNK_ROWS, nnz]`` float64
+buffer of picked weights (8 MB at doc nnz 128) for the whole draw: about
+40 MB a worker beside the output arrays. The blocks consume each chunk's
+stream in the order one whole-chunk draw would (the Gumbel keys of every
+row, then the log-normal values of every row), so the rows are the same
+bit for bit. Whole-chunk temporaries of 25-50 MB, allocated and freed
+many times a chunk, are what this avoids.
 """
 from __future__ import annotations
 
 import dataclasses
 import os
+import threading
 from concurrent.futures import ThreadPoolExecutor
 
 import numpy as np
 
 CHUNK_ROWS = 8192      # rows per independently seeded draw
-MAX_THREADS = 16       # chunks drawn at once (host memory ~0.4 GB each)
+ROW_BLOCK = 512        # rows of a chunk worked at once (temporaries <= 3 MB)
+MAX_THREADS = 16       # chunks drawn at once (host memory ~40 MB each)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -82,13 +94,12 @@ class _Topics:
         self.pos[t, self.coords] = np.arange(cfg.topic_coords)
 
 
-def _draw_chunk(topics: _Topics, cfg: SyntheticSparseConfig, rng,
-                t1: np.ndarray, t2: np.ndarray, nnz: int,
-                primary_scale: float):
-    """One chunk of rows: candidates are the primary topic's coords
-    followed by the secondary's; a coordinate both share keeps the max
-    of its two logits in the primary half and drops out of the
-    secondary half."""
+def _candidates(topics: _Topics, cfg: SyntheticSparseConfig,
+                t1: np.ndarray, t2: np.ndarray, primary_scale: float):
+    """Candidate coordinates and logits of rows of topics (t1, t2): the
+    primary topic's coords followed by the secondary's; a coordinate
+    both share keeps the max of its two logits in the primary half and
+    drops out of the secondary half."""
     lg1 = topics.log_w[t1] * primary_scale                  # [n, m]
     cand = topics.coords[t1]
     if cfg.doc_topic_mix > 1:
@@ -102,13 +113,30 @@ def _draw_chunk(topics: _Topics, cfg: SyntheticSparseConfig, rng,
         lg2[rows, j[rows, cols]] = -np.inf
         cand = np.concatenate([cand, topics.coords[t2]], axis=1)
         lg1 = np.concatenate([lg1, lg2], axis=1)
-    pick = _sample_rows(rng, lg1, nnz)                      # [n, nnz]
-    coords = np.take_along_axis(cand, pick, axis=1)
-    base = np.exp(np.take_along_axis(lg1, pick, axis=1))
-    vals = base * rng.lognormal(0.0, cfg.value_sigma * 0.5,
-                                size=coords.shape)
-    vals = vals / np.maximum(vals.max(axis=-1, keepdims=True), 1e-9) * 3.0
-    return coords.astype(np.int32), vals.astype(np.float32)
+    return cand, lg1
+
+
+def _draw_chunk(topics: _Topics, cfg: SyntheticSparseConfig, rng,
+                t1: np.ndarray, t2: np.ndarray, nnz: int,
+                primary_scale: float, coords: np.ndarray, vals: np.ndarray,
+                base: np.ndarray) -> None:
+    """One chunk of rows into ``coords`` / ``vals`` (its ``[n, nnz]``
+    rows of the output), ``ROW_BLOCK`` rows at a time: every block's
+    Gumbel draw first, then every block's log-normal values, each in
+    row order, as one whole-chunk draw consumes ``rng``. ``base``
+    (``[n, nnz]`` float64) holds the picked weights between the two."""
+    n = t1.size
+    blocks = [slice(lo, min(lo + ROW_BLOCK, n))
+              for lo in range(0, n, ROW_BLOCK)]
+    for b in blocks:
+        cand, lg = _candidates(topics, cfg, t1[b], t2[b], primary_scale)
+        pick = _sample_rows(rng, lg, nnz)                   # [rows, nnz]
+        coords[b] = np.take_along_axis(cand, pick, axis=1)
+        base[b] = np.exp(np.take_along_axis(lg, pick, axis=1))
+    for b in blocks:
+        v = base[b] * rng.lognormal(0.0, cfg.value_sigma * 0.5,
+                                    size=(b.stop - b.start, nnz))
+        vals[b] = v / np.maximum(v.max(axis=-1, keepdims=True), 1e-9) * 3.0
 
 
 def _draw(topics: _Topics, cfg: SyntheticSparseConfig, stream: int,
@@ -117,6 +145,7 @@ def _draw(topics: _Topics, cfg: SyntheticSparseConfig, stream: int,
     coords = np.empty((n_rows, nnz), np.int32)
     vals = np.empty((n_rows, nnz), np.float32)
     t1 = np.empty((n_rows,), np.int64)
+    worker = threading.local()      # each thread's buffer of picked weights
 
     def chunk(c: int) -> None:
         rng = np.random.default_rng([cfg.seed, stream, c])
@@ -124,8 +153,10 @@ def _draw(topics: _Topics, cfg: SyntheticSparseConfig, stream: int,
         n = min(CHUNK_ROWS, n_rows - lo)
         a = rng.integers(0, cfg.n_topics, n)
         b = rng.integers(0, cfg.n_topics, n)
-        coords[lo:lo + n], vals[lo:lo + n] = _draw_chunk(
-            topics, cfg, rng, a, b, nnz, primary_scale)
+        if not hasattr(worker, "base"):
+            worker.base = np.empty((CHUNK_ROWS, nnz), np.float64)
+        _draw_chunk(topics, cfg, rng, a, b, nnz, primary_scale,
+                    coords[lo:lo + n], vals[lo:lo + n], worker.base[:n])
         t1[lo:lo + n] = a
 
     n_chunks = -(-n_rows // CHUNK_ROWS)

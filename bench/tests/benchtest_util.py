@@ -53,6 +53,21 @@ def shard_layout(n: int):
     return edit
 
 
+def check_layouts(bench: dict, root: str = ROOT, bench_dir: str = BENCH):
+    """The layout rule over a whole benchmark: a configuration without
+    ``layout`` is not sharded, and one with it is accepted by
+    ``spec.check_layout`` in every cell that names it."""
+    for c in bench["configs"]:
+        cfg = spec.load_config(bench, c["name"], root)
+        if "layout" not in cfg:
+            assert spec.shards(cfg) == 0
+            continue
+        for w in bench["workloads"]:
+            if w["config"] == c["name"]:
+                spec.check_layout(w, cfg,
+                                  spec.load_traffic(w["traffic"], bench_dir))
+
+
 def run_tiny(name: str, seed: int = 2 ** 33 + 5, seconds: float = 0.5,
              cfg_edit=None, chips: int | None = None):
     """One run of a cut-down cell on the CPU, past the harness's look

@@ -10,8 +10,8 @@ import sys
 
 import pytest
 
-from benchtest_util import (ROOT, load_run, run_tiny, shard_layout,
-                            spec, tiny_cell)
+from benchtest_util import (ROOT, check_layouts, load_run, run_tiny,
+                            shard_layout, spec, tiny_cell)
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 
@@ -51,14 +51,37 @@ def live_blocks_probed(live_per_list, probed):
     return orig_live(live_per_list, probed)
 workbytes.live_blocks_probed = live_blocks_probed
 
-from repro.serve import replica
-orig_search = replica.search_shard
-calls = {}
-def search_shard(view, q_coords, q_vals, shard_offset, p, n_docs):
-    dev = next(iter(view.block_len.devices())).id
-    calls[dev] = calls.get(dev, 0) + 1
-    return orig_search(view, q_coords, q_vals, shard_offset, p, n_docs)
-replica.search_shard = search_shard
+# every query of the pool gets a copy of itself, scaled up, planted in the
+# middle of each quarter of the corpus (one shard's range whatever the
+# split): each answer holds its query's four copies only if every shard
+# served the launch that carried it
+orig_data = run.make_data
+planted = {}
+def make_data(cfg, mix, seed):
+    dc, dv, qc, qv = orig_data(cfg, mix, seed)
+    n, nq = dc.shape[0], qc.shape[0]
+    planted["ids"] = np.zeros((nq, 4), np.int64)
+    for s in range(4):
+        for q in range(nq):
+            d = s * n // 4 + n // 8 + q
+            rest = dc[d][~np.isin(dc[d], qc[q])][:dc.shape[1] - qc.shape[1]]
+            dc[d] = np.concatenate([qc[q], rest])
+            dv[d] = np.concatenate([qv[q] * (40.0 - 4 * s),
+                                    np.full(rest.size, 1e-3, np.float32)])
+            planted["ids"][q, s] = d
+    return dc, dv, qc, qv
+run.make_data = make_data
+
+orig_judge = run.judge
+def judge(w, prep, ref, cfg):
+    if "planted_found" not in out:
+        ok = w["answered"]
+        want = planted["ids"][w["qidx"][ok]]              # [answers, 4]
+        found = (w["ids"][ok][:, None, :] == want[:, :, None]).any(axis=-1)
+        out["planted_found"] = found.all(axis=0).tolist()  # each shard's
+        out["planted_answers"] = int(ok.sum())
+    return orig_judge(w, prep, ref, cfg)
+run.judge = judge
 
 def summary(checks, res):
     return dict(correct=res["correct"], attempted=res["attempted"],
@@ -69,14 +92,20 @@ def summary(checks, res):
 
 out["program"] = summary(*run_tiny("splade-r90.online",
                                    cfg_edit=shard_layout(4), chips=4))
-out["shard_calls"] = {str(k): v for k, v in sorted(calls.items())}
 
-def altered(view, q_coords, q_vals, shard_offset, p, n_docs):
-    s, g, e = orig_search(view, q_coords, q_vals, shard_offset, p, n_docs)
-    if shard_offset == 0:
-        g = g.at[0, 0].set((g[0, 0] + 1) % n_docs)
-    return s, g, e
-replica.search_shard = altered
+# shard 0's best id of each launch's first query, one higher, planted
+# where each shard's result is globalized (both the replica server's
+# shard launch and the shard_map search call mask_shard_topk)
+import jax.numpy as jnp
+from repro.core import distributed
+orig_mask = distributed.mask_shard_topk
+def altered(scores, ids, fwd, shard_offset, n_docs=None):
+    s, g = orig_mask(scores, ids, fwd, shard_offset, n_docs)
+    n = n_docs or fwd.coords.shape[0]
+    return s, jnp.where(shard_offset == 0, g.at[0, 0].set((g[0, 0] + 1) % n),
+                        g)
+distributed.mask_shard_topk = altered
+jax.clear_caches()      # the shard programs are traced again, with the fault
 out["altered"] = summary(*run_tiny("splade-r90.online",
                                    cfg_edit=shard_layout(4), chips=4))
 
@@ -127,9 +156,10 @@ def test_sharded_program_is_correct(four):
 
 
 def test_every_shard_serves_every_launch(four):
-    calls = four["shard_calls"]
-    assert len(calls) == 4 and len(set(calls.values())) == 1
-    assert list(calls.values())[0] > 0
+    """Every answer of the window holds the copy of its query planted in
+    each shard's range."""
+    assert four["planted_answers"] > 0
+    assert four["planted_found"] == [True] * 4
 
 
 def test_one_shard_is_built_on_each_device(four):
@@ -147,9 +177,9 @@ def test_live_blocks_are_summed_over_the_shards(four):
 
 
 def test_an_answer_altered_in_one_shard_is_not_correct(four):
-    """Shard 0's best id of each launch's first query, one higher: its
-    served score is not its own (``score_gap``), or it repeats an id of
-    the answer (``bad_answers``)."""
+    """Shard 0's best id of each launch's first query, one higher where
+    the shard's result is globalized: its served score is not its own
+    (``score_gap``), or it repeats an id of the answer (``bad_answers``)."""
     r = four["altered"]
     assert not r["correct"]
     assert not (r["checks"]["score_gap"][2] and r["checks"]["bad_answers"][2])
@@ -191,9 +221,9 @@ def test_spec_refuses_a_malformed_layout(layout):
 
 
 def test_a_configuration_without_layout_is_not_sharded():
-    bench = spec.load_benchmark()
-    for c in bench["configs"]:
-        assert spec.shards(spec.load_config(bench, c["name"])) == 0
+    """Only a configuration without ``layout`` has to be unsharded; one
+    with it has to be accepted in every cell that names it."""
+    check_layouts(spec.load_benchmark())
 
 
 class _Recorder:
@@ -219,7 +249,8 @@ def _params(cfg):
     return SearchParams(k=s["k"], cut=s["cut"], block_budget=s["block_budget"])
 
 
-@pytest.mark.parametrize("name", ["splade-r90.online", "splade-r97.bulk"])
+@pytest.mark.parametrize("name", ["splade-r90.online", "splade-r97.bulk",
+                                  "splade-r90.bulk"])
 def test_make_server_gives_an_unsharded_cell_the_same_server(name,
                                                              recorded):
     """The server class, search parameters and arguments a configuration

@@ -2,10 +2,11 @@
 and every configuration, traffic mix and metric found by name."""
 import json
 import os
+import shutil
 
 import pytest
 
-from benchtest_util import BENCH, ROOT, spec
+from benchtest_util import BENCH, ROOT, check_layouts, spec
 
 BENCHMARK = spec.load_benchmark()
 
@@ -27,28 +28,84 @@ def test_check_names_refuses_a_bad_unit():
     assert "queries per second" in spec.check_names(b)
 
 
-def test_every_cell_finds_its_config_and_traffic_by_name():
-    for w in BENCHMARK["workloads"]:
-        cfg = spec.load_config(BENCHMARK, w["config"])
+def finds_every_part_by_name(bench, root=ROOT, bench_dir=BENCH):
+    """Each cell's configuration file and traffic mix, found by name
+    under ``root`` and ``bench_dir``."""
+    for w in bench["workloads"]:
+        cfg = spec.load_config(bench, w["config"], root)
         assert cfg["name"] == w["config"]
-        mix = spec.load_traffic(w["traffic"])
+        mix = spec.load_traffic(w["traffic"], bench_dir)
         assert mix["api"] in ("submit", "search")
-        entry = spec.config_entry(BENCHMARK, w["config"])
+        entry = spec.config_entry(bench, w["config"])
         assert entry["file"].startswith("bench/configs/")
-        assert os.path.exists(os.path.join(ROOT, entry["file"]))
+        assert os.path.exists(os.path.join(root, entry["file"]))
 
 
-def test_every_metric_has_a_reader_and_every_cell_reports_enough():
-    for w in BENCHMARK["workloads"]:
-        e2e = spec.cell_metrics(BENCHMARK, w["name"], "end_to_end")
-        per = spec.cell_metrics(BENCHMARK, w["name"], "per_layer")
+def reports_enough(bench, bench_dir=BENCH):
+    """Every metric a cell reports has a reader under ``bench_dir``, and
+    every cell reports ``setup_s``, another end-to-end metric and a
+    per-layer metric that moves one of its end-to-end metrics."""
+    for w in bench["workloads"]:
+        e2e = spec.cell_metrics(bench, w["name"], "end_to_end")
+        per = spec.cell_metrics(bench, w["name"], "per_layer")
         assert "setup_s" in [m["name"] for m in e2e] and len(e2e) >= 2
         assert per
         e2e_names = {m["name"] for m in e2e}
         for m in e2e + per:
-            assert callable(spec.metric_reader(m["name"]))
+            assert callable(spec.metric_reader(m["name"], bench_dir))
         for m in per:
             assert m["moves"] in e2e_names
+
+
+def test_every_cell_finds_its_config_and_traffic_by_name():
+    finds_every_part_by_name(BENCHMARK)
+
+
+def test_every_metric_has_a_reader_and_every_cell_reports_enough():
+    reports_enough(BENCHMARK)
+
+
+def test_whole_benchmark_checks_accept_a_sharded_configuration(tmp_path):
+    """A copy of the benchmark with one more configuration, the r90 one
+    split over four chips (``"layout": {"shards": 4}``), and a four-chip
+    ``online`` cell of it, listed under the metrics a sharded run reads
+    (PERF.md, section 3): every per-configuration and per-cell check of
+    the benchmark's tests holds, so a configuration like it can be added
+    with files and entries alone."""
+    bench = json.loads(json.dumps(BENCHMARK))
+    bench_dir = tmp_path / "bench"
+    for part in ("configs", "traffic", "metrics"):
+        shutil.copytree(os.path.join(BENCH, part), bench_dir / part)
+    cfg = spec.load_config(bench, "splade-msmarco-1m-r90")
+    cfg["name"] = "splade-msmarco-4m-r90-shard4"
+    cfg["corpus"]["n_docs"] = 4 * cfg["corpus"]["n_docs"]
+    cfg["layout"] = {"shards": 4}
+    (bench_dir / "configs" / f"{cfg['name']}.json").write_text(
+        json.dumps(cfg))
+    bench["configs"].append(dict(
+        spec.config_entry(bench, "splade-msmarco-1m-r90"), name=cfg["name"],
+        file=f"bench/configs/{cfg['name']}.json"))
+    cell = {"name": "splade-r90-shard4.online", "config": cfg["name"],
+            "traffic": "online", "chips": 4,
+            "why": "four shards of the corpus behind one online front end"}
+    bench["workloads"].append(cell)
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        if m["name"] in ("p50_ms", "p95_ms", "queue_wait_ms.online",
+                         "batch_occupancy.online", "batch_wait_ms.online",
+                         "idle_share.online"):
+            m["workloads"].append(cell["name"])
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+
+    bench = spec.load_benchmark(str(tmp_path))
+    assert spec.check_names(bench) == []
+    finds_every_part_by_name(bench, str(tmp_path), str(bench_dir))
+    reports_enough(bench, str(bench_dir))
+    check_layouts(bench, str(tmp_path), str(bench_dir))
+    assert spec.shards(spec.load_config(bench, cfg["name"],
+                                        str(tmp_path))) == 4
+    bench["workloads"][-1]["chips"] = 1
+    with pytest.raises(spec.SpecError, match="must equal the cell's chips"):
+        check_layouts(bench, str(tmp_path), str(bench_dir))
 
 
 def test_an_unknown_name_is_an_error():
